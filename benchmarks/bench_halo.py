@@ -77,7 +77,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator, policy_for_mode
 from repro.halo import HaloSpec, halo_exchange, make_halo_plan
 
@@ -133,7 +133,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator, FixedPolicy, collective_payload_bytes
 from repro.halo import HaloSpec, halo_exchange, make_halo_plan
 
@@ -369,7 +369,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator
 from repro.halo import (HaloSpec, STENCIL26, halo_exchange, make_halo_plan,
                         make_halo_types, overlap_region_descriptors,
@@ -450,7 +450,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator, RleWire, collective_payload_bytes
 from repro.comm.wireplan import reschedule
 from repro.core import FLOAT, Subarray
@@ -602,10 +602,12 @@ def run(assert_ragged: bool = False, assert_program: bool = False,
         assert_overlap: bool = False, assert_scale: bool = False,
         assert_compress: bool = False,
         padded_allowance: float = None) -> None:
+    # CPU gates, not chip measurements: the children run on 8 virtual
+    # CPU devices whatever the machine holds
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     if padded_allowance is not None:
         env["REPRO_PADDED_ALLOWANCE"] = str(padded_allowance)
     gate = (assert_ragged or assert_program or assert_overlap

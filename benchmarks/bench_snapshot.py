@@ -227,7 +227,7 @@ def snapshot(iters: int = 10) -> dict:
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import FLOAT, Subarray
     from repro.measure.bench import measure_compress_table
 

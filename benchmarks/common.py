@@ -1,10 +1,12 @@
 """Shared benchmark utilities.
 
-All benchmarks print ``name,us_per_call,derived`` CSV rows (spec) and
-run on the CPU container: Pallas kernels execute in interpret mode, so
-absolute times are *proxies* — the quantities that transfer to TPU are
-the relative orderings, the canonicalization/caching behavior (pure
-host code), and the modeled values; every table notes which is which.
+All benchmarks print ``name,us_per_call,derived`` CSV rows (spec).
+These suites are CPU gates, not chip measurements: they run on XLA's
+CPU backend with Pallas kernels in interpret mode, so every time they
+print is a CPU proxy and names no device metric.  What they check —
+orderings, canonicalization/caching behaviour (pure host code), byte
+counts and modeled values — holds on any backend.  ``chip_smoke.py``
+is the check that runs on the TPU.
 """
 
 from __future__ import annotations

@@ -40,6 +40,7 @@ via :func:`policy_for_mode`).
 
 from __future__ import annotations
 
+import math
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -49,7 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core.commit import CommittedType, TypeRegistry, WireSegment
 from repro.core.datatypes import Datatype
 from repro.core.strided_block import StridedBlock
@@ -78,7 +78,12 @@ from repro.comm.perfmodel import (
     SystemParams,
     TPU_V5E,
 )
-from repro.comm.wireplan import WireGroup, WirePlan, plan_wire
+from repro.comm.wireplan import (
+    WireGroup,
+    WirePlan,
+    has_ragged_all_to_all,
+    plan_wire,
+)
 
 __all__ = [
     "Strategy",
@@ -272,6 +277,22 @@ class Strategy:
     ) -> jax.Array:
         raise TypeError(f"strategy {self.name!r} has no local unpack kernel")
 
+    def pack_planes(
+        self, view: jax.Array, geom: PackGeometry, interpret: bool
+    ) -> Optional[jax.Array]:
+        """Pack a 3D plane-block geometry straight from the buffer's own
+        (planes, view_rows, pitch) word view (see ``repro.kernels.ops``);
+        None: this strategy takes the byte path instead."""
+        return None
+
+    def unpack_planes(
+        self, view: jax.Array, packed: jax.Array, geom: PackGeometry,
+        interpret: bool,
+    ) -> Optional[jax.Array]:
+        """Inverse of :meth:`pack_planes`: the updated word view, given
+        the (planes, rows, lanes) packed words; None: byte path."""
+        return None
+
     # ---------------------------------------------------------------------
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return f"<Strategy {self.name}>"
@@ -326,6 +347,12 @@ class Rows(Strategy):
             kernel = unpack_rows
         return ops.run_unpack_kernel(b, packed, geom, kernel, interpret)
 
+    def pack_planes(self, view, geom, interpret):
+        return ops.packed_bytes(pack_rows(view, geom, interpret=interpret))
+
+    def unpack_planes(self, view, packed, geom, interpret):
+        return unpack_rows(view, packed, geom, interpret=interpret)
+
 
 def _dma_pack_kernel(src2d, geom, interpret=False):
     return pack_dma(src2d, geom, VMEM_BUDGET_BYTES, interpret=interpret)
@@ -336,13 +363,18 @@ def _dma_unpack_kernel(dst2d, pk3, geom, interpret=False):
 
 
 class Dma(Strategy):
-    """Strided-descriptor DMA kernel ≙ the paper's "staged" method: one
-    DMA per row-chunk, no pitch over-fetch."""
+    """DMA kernel ≙ the paper's "staged" method: one manually issued DMA
+    of whole pitch rows per 8-aligned row-chunk (TPU DMAs move whole
+    tiles).  The analytic price below still assumes no pitch over-fetch;
+    a calibrated table replaces it with what the chip measures."""
 
     name = "dma"
 
     def applicable(self, ct: CommittedType) -> bool:
-        return ct.block is not None and plan_geometry(ct.block) is not None
+        if ct.block is None:
+            return False
+        geom = plan_geometry(ct.block)
+        return geom is not None and not geom.plane_block
 
     def model_pack(self, model, ct, incount):
         p, size, sb, m = _analytic_prologue(model, self, ct, incount)
@@ -353,12 +385,12 @@ class Dma(Strategy):
         return p.kernel_launch + chunks * p.dma_setup + 2 * size / p.hbm_bw
 
     def pack_leaf(self, b, sb, geom, interpret):
-        if geom is None:
+        if geom is None or geom.plane_block:
             return refk.pack_ref(b, sb)
         return ops.run_pack_kernel(b, geom, _dma_pack_kernel, interpret)
 
     def unpack_leaf(self, b, packed, sb, geom, interpret):
-        if geom is None:
+        if geom is None or geom.plane_block:
             return refk.unpack_ref(b, packed, sb)
         return ops.run_unpack_kernel(b, packed, geom, _dma_unpack_kernel, interpret)
 
@@ -386,6 +418,78 @@ class XlaBlocks(Strategy):
         if geom is None:
             return refk.unpack_ref(b, packed, sb)
         return refk.unpack_xla_blocks(b, packed, sb)
+
+    def pack_planes(self, view, geom, interpret):
+        z0, y0 = divmod(geom.q, geom.view_rows)
+        return ops.packed_bytes(jnp.stack([
+            view[z0 + p, y0 + i, geom.r:geom.r + geom.lanes]
+            for p in range(geom.planes) for i in range(geom.rows)
+        ]))
+
+    def unpack_planes(self, view, packed, geom, interpret):
+        z0, y0 = divmod(geom.q, geom.view_rows)
+        for p in range(geom.planes):
+            for i in range(geom.rows):
+                view = view.at[z0 + p, y0 + i, geom.r:geom.r + geom.lanes].set(
+                    packed[p, i]
+                )
+        return view
+
+
+def _ragged_exchange(
+    wire: jax.Array, plan: WirePlan, sizes: Sequence[int], axis: str
+) -> List[jax.Array]:
+    """One native ``ragged_all_to_all`` moving ``sizes[g]`` bytes of each
+    delta class ``g``; returns each class's received bytes.
+
+    Per-peer metadata semantics: input_offsets/send_sizes and
+    output_offsets are indexed by DESTINATION peer — the chunk this rank
+    sends to peer d is operand[in_off[d]:+in_sz[d]] and lands at
+    out_off[d] in d's OUTPUT buffer.  A group travels under the same
+    global offset on both sides (the flat layout is rank-uniform), so
+    out_off mirrors in_off.  recv_sizes is indexed by SOURCE peer: the
+    bytes arriving from s are the group whose recv_rows entry names s.
+
+    The operand goes over as rows of the widest power-of-two byte count
+    (up to 512 B) that divides every offset, size and the buffer length:
+    the TPU pads each operand row to a whole tile, so a flat byte operand
+    costs ~512x its size in scratch.  The bytes sent stay exact.
+    """
+    ngroups = len(plan.groups)
+    in_off = np.zeros((plan.nranks, plan.nranks), np.int64)
+    in_sz = np.zeros_like(in_off)
+    out_off = np.zeros_like(in_off)
+    recv_sz = np.zeros_like(in_off)
+    for r in range(plan.nranks):
+        for d, g in enumerate(plan.send_rows[r]):
+            if g < ngroups:
+                in_off[r, d] = plan.group_offsets[g]
+                in_sz[r, d] = sizes[g]
+                out_off[r, d] = plan.group_offsets[g]
+        for g, s in enumerate(plan.recv_rows[r]):
+            recv_sz[r, s] = sizes[g]
+    unit = math.gcd(
+        int(wire.shape[0]),
+        *(int(v) for t in (in_off, in_sz, recv_sz) for v in t.flat),
+    )
+    row = math.gcd(unit & -unit, 512) if unit else 1
+    if row >= 4:
+        operand = ops.as_words(wire, 4).reshape(-1, row // 4)
+    else:
+        operand = wire.reshape(-1, row)
+    me = lax.axis_index(axis)
+    got = lax.ragged_all_to_all(
+        operand,
+        jnp.zeros_like(operand),
+        *(jnp.asarray(t // row, np.int32)[me]
+          for t in (in_off, in_sz, out_off, recv_sz)),
+        axis_name=axis,
+    )
+    got = ops.packed_bytes(got) if row >= 4 else got.reshape(-1)
+    return [
+        lax.dynamic_slice(got, (goff,), (sz,))
+        for goff, sz in zip(plan.group_offsets, sizes)
+    ]
 
 
 class Gather(Strategy):
@@ -429,6 +533,12 @@ class Auto(Strategy):
 
     def unpack_leaf(self, b, packed, sb, geom, interpret):
         return static_choice(geom).unpack_leaf(b, packed, sb, geom, interpret)
+
+    def pack_planes(self, view, geom, interpret):
+        return static_choice(geom).pack_planes(view, geom, interpret)
+
+    def unpack_planes(self, view, packed, geom, interpret):
+        return static_choice(geom).unpack_planes(view, packed, geom, interpret)
 
 
 class Bounding(Strategy):
@@ -607,6 +717,8 @@ def static_choice(geom: Optional[PackGeometry]) -> Strategy:
     device)."""
     if geom is None:
         return REF
+    if geom.plane_block:
+        return ROWS  # the DMA kernel needs 8-aligned row chunks
     return ROWS if geom.overfetch <= 4.0 else DMA
 
 
@@ -1260,39 +1372,13 @@ class Communicator:
             # stream length — a strict PREFIX of its capacity slot (the
             # compressed formats interleave run records, so truncation
             # loses nothing the decoder needs).  Native ragged collective
-            # with per-class stream sizes when the primitive exists;
+            # with per-class stream sizes where the backend runs it;
             # truncated per-class ppermutes otherwise.  Bit-exact vs the
             # capacity path for payloads within the probed stream budget.
             if len(plan.stream_bytes) != plan.ngroups:
                 raise ValueError("varlen schedule on a stream-unannotated plan")
-            if compat.has_ragged_all_to_all() and plan.fused:
-                ngroups = len(plan.groups)  # pragma: no cover - needs new JAX
-                in_off = np.zeros((plan.nranks, plan.nranks), np.int32)
-                in_sz = np.zeros_like(in_off)
-                out_off = np.zeros_like(in_off)
-                recv_sz = np.zeros_like(in_off)
-                for r in range(plan.nranks):
-                    for d, g in enumerate(plan.send_rows[r]):
-                        if g < ngroups:
-                            in_off[r, d] = plan.group_offsets[g]
-                            in_sz[r, d] = plan.stream_bytes[g]
-                            out_off[r, d] = plan.group_offsets[g]
-                    for g, s in enumerate(plan.recv_rows[r]):
-                        recv_sz[r, s] = plan.stream_bytes[g]
-                me = lax.axis_index(axis)
-                got = compat.ragged_all_to_all(
-                    wire,
-                    jnp.zeros_like(wire),
-                    jnp.asarray(in_off)[me],
-                    jnp.asarray(in_sz)[me],
-                    jnp.asarray(out_off)[me],
-                    jnp.asarray(recv_sz)[me],
-                    axis_name=axis,
-                )
-                return [
-                    lax.dynamic_slice(got, (goff,), (sb,))
-                    for goff, sb in zip(plan.group_offsets, plan.stream_bytes)
-                ]
+            if has_ragged_all_to_all() and plan.fused:
+                return _ragged_exchange(wire, plan, plan.stream_bytes, axis)
             rows = []
             for goff, sb, grp in zip(
                 plan.group_offsets, plan.stream_bytes, plan.groups
@@ -1376,43 +1462,11 @@ class Communicator:
             return [by_group[g] for g in range(len(plan.groups))]
 
         # "ragged": one native ragged collective — exact bytes, one op.
-        # Requires lax.ragged_all_to_all (the planner only selects this
-        # schedule when repro.compat reports it available).
-        # Per-peer metadata semantics: input_offsets/send_sizes and
-        # output_offsets are indexed by DESTINATION peer — the chunk this
-        # rank sends to peer d is operand[in_off[d]:+in_sz[d]] and lands
-        # at out_off[d] in d's OUTPUT buffer.  A group travels under the
-        # same global offset on both sides (the flat layout is
-        # rank-uniform), so out_off mirrors in_off.  recv_sizes is
-        # indexed by SOURCE peer: the bytes arriving from s are the
-        # group whose recv_rows entry names s.
-        ngroups = len(plan.groups)  # pragma: no cover - needs new JAX
-        in_off = np.zeros((plan.nranks, plan.nranks), np.int32)
-        in_sz = np.zeros_like(in_off)
-        out_off = np.zeros_like(in_off)
-        recv_sz = np.zeros_like(in_off)
-        for r in range(plan.nranks):
-            for d, g in enumerate(plan.send_rows[r]):
-                if g < ngroups:
-                    in_off[r, d] = plan.group_offsets[g]
-                    in_sz[r, d] = plan.groups[g].nbytes
-                    out_off[r, d] = plan.group_offsets[g]
-            for g, s in enumerate(plan.recv_rows[r]):
-                recv_sz[r, s] = plan.groups[g].nbytes
-        me = lax.axis_index(axis)
-        got = compat.ragged_all_to_all(
-            wire,
-            jnp.zeros_like(wire),
-            jnp.asarray(in_off)[me],
-            jnp.asarray(in_sz)[me],
-            jnp.asarray(out_off)[me],
-            jnp.asarray(recv_sz)[me],
-            axis_name=axis,
+        # Requires a backend that runs lax.ragged_all_to_all (the planner
+        # only selects this schedule when has_ragged_all_to_all says so).
+        return _ragged_exchange(
+            wire, plan, tuple(g.nbytes for g in plan.groups), axis
         )
-        return [
-            lax.dynamic_slice(got, (goff,), (grp.nbytes,))
-            for goff, grp in zip(plan.group_offsets, plan.groups)
-        ]
 
     def _phase_predictions(
         self, send_cts, strategies, plan

@@ -282,10 +282,7 @@ class RleWire(Strategy):
         cap = self.wire_bytes(ct, incount)
         if isinstance(buf, jax.core.Tracer):
             return cap  # tracer: nothing to probe
-        try:
-            member = np.asarray(ops.pack(jnp.asarray(buf), ct, incount=incount))
-        except Exception:
-            return cap
+        member = np.asarray(ops.pack(jnp.asarray(buf), ct, incount=incount))
         n = member.size
         if n == 0:
             return cap

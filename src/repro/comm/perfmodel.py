@@ -731,11 +731,13 @@ class PerfModel:
         correction hops), which is also what keeps the inter == intra
         oracle bit-for-bit.
         """
-        if native is None:
-            from repro.compat import has_ragged_all_to_all
+        from repro.comm.wireplan import (
+            GROUPED_FALLBACK_RANK_FACTOR,
+            has_ragged_all_to_all,
+        )
 
+        if native is None:
             native = has_ragged_all_to_all()
-        from repro.comm.wireplan import GROUPED_FALLBACK_RANK_FACTOR
 
         costs = {"grouped": self._price_schedule(plan, "grouped", axis)}
         stream = getattr(plan, "stream_bytes", ())

@@ -11,8 +11,8 @@ descriptors, no class padding, no row equalization — and then picks the
 cheapest wire **schedule** that can carry that ragged layout:
 
 ``ragged``
-    one ``lax.ragged_all_to_all`` collective (requires a JAX that has
-    the primitive — see :func:`repro.compat.has_ragged_all_to_all`).
+    one ``lax.ragged_all_to_all`` collective (requires a backend that
+    runs the op natively — see :func:`has_ragged_all_to_all`).
     Exact bytes, one wire op.
 ``uniform``
     one plain ``all_to_all`` over destination-ordered rows.  A uniform
@@ -34,8 +34,8 @@ cheapest wire **schedule** that can carry that ragged layout:
     :class:`~repro.comm.compress.RleWire`), so the compressed bytes —
     not the capacity — are the bytes on the wire.  Rides one truncated
     ``ppermute`` per class, or a single native ``ragged_all_to_all``
-    with per-class stream sizes when the primitive is available
-    (:func:`repro.compat.has_ragged_all_to_all`).  Bit-exact vs the
+    with per-class stream sizes when the backend runs the op natively
+    (:func:`has_ragged_all_to_all`).  Bit-exact vs the
     capacity path: the stream is a strict prefix of the capacity wire
     and the decoder derives the run count from the wire length.
 ``tiered``
@@ -75,6 +75,7 @@ from repro.comm.topology import Topology, classify_and_coalesce
 __all__ = [
     "WireGroup",
     "WirePlan",
+    "has_ragged_all_to_all",
     "plan_wire",
     "reschedule",
     "GROUPED_FALLBACK_RANK_FACTOR",
@@ -95,6 +96,17 @@ WIRE_COLLECTIVES = ("ppermute", "all_to_all", "ragged_all_to_all")
 #: annotation, "varlen" a stream-length annotation; the exact ladder
 #: only ever picks the first three)
 WIRE_SCHEDULES = ("ragged", "uniform", "grouped", "tiered", "varlen")
+
+
+def has_ragged_all_to_all(devices=None) -> bool:
+    """Whether ``devices`` (default: the default backend's) execute
+    ``lax.ragged_all_to_all`` natively.  The TPU does; XLA:CPU has no
+    emitter for the op, so plans for CPU devices take the grouped
+    per-class ``ppermute`` schedule instead."""
+    import jax
+
+    devs = jax.devices() if devices is None else devices
+    return all(d.platform == "tpu" for d in devs)
 
 
 @dataclass(frozen=True)
@@ -322,8 +334,6 @@ def plan_wire(
     the permutations' (e.g. a single-host test mesh planned against a
     production topology)."""
     if native is None:
-        from repro.compat import has_ragged_all_to_all
-
         native = has_ragged_all_to_all()
     n = len(perms)
     if len(sizes) != n:
@@ -493,11 +503,11 @@ def _walk_jaxpr(jaxpr, counts: Dict[str, int]) -> None:
 
 
 def _sub_jaxprs(val):
-    import jax.core as jcore
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    if isinstance(val, jcore.Jaxpr):
+    if isinstance(val, Jaxpr):
         yield val
-    elif isinstance(val, jcore.ClosedJaxpr):
+    elif isinstance(val, ClosedJaxpr):
         yield val.jaxpr
     elif isinstance(val, (tuple, list)):
         for v in val:

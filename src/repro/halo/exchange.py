@@ -49,7 +49,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm.api import (
     Communicator,
     Request,

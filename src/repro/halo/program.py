@@ -50,7 +50,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm.api import as_communicator
 from repro.comm.perfmodel import ProgramEstimate
 from repro.core.datatypes import FLOAT, Named

@@ -39,6 +39,13 @@ class PackGeometry:
     All units are W-byte words unless suffixed ``_bytes``.  The source is
     reshaped to a (row-pitch) 2D view ``(R, pitch)``; block ``(p, i)``'s
     first word then lives at row ``q + p*plane_rows + i`` column ``r``.
+
+    A TPU block's second-minor extent must be a multiple of the 8-row
+    sublane tile or span its whole dimension.  When no 8-aligned row
+    group divides the layout (``group == 0``), the kernels instead move
+    one whole *view plane* of ``view_rows`` rows per grid step — a plane
+    of a 3D object, or the whole 2D view — and cut the object's rows out
+    of it in fast memory (``plane_block``).
     """
 
     word_bytes: int      # W
@@ -49,8 +56,18 @@ class PackGeometry:
     q: int               # start row of the 2D view
     r: int               # column offset within a row
     plane_rows: int      # strides[2] // strides[1] (0 for 2D)
-    group: int           # G: rows handled per grid step
+    group: int           # G: rows handled per grid step (0: plane blocks)
     rows_padded: int     # 2D-view rows after tail padding (multiple of G)
+
+    @property
+    def plane_block(self) -> bool:
+        return self.group == 0
+
+    @property
+    def view_rows(self) -> int:
+        """Rows of one view plane (plane-block mode): a 3D object's plane
+        stride, or the whole 2D view."""
+        return self.plane_rows or self.rows_padded
 
     @property
     def out_words(self) -> int:
@@ -58,24 +75,29 @@ class PackGeometry:
 
     @property
     def grid(self):
+        if self.plane_block:
+            return (self.planes,)
         return (self.planes, self.rows // self.group)
 
     @property
     def overfetch(self) -> float:
-        """HBM words fetched per useful word (row-kernel reads the full
-        pitch).  Feeds the §5 performance model."""
+        """HBM words fetched per useful word (the kernels read the full
+        pitch; plane blocks read whole planes).  Feeds the §5
+        performance model."""
+        if self.plane_block:
+            return self.view_rows * self.pitch / max(self.rows * self.lanes, 1)
         return self.pitch / max(self.lanes, 1)
 
 
 def _choose_group(rows: int, q: int, plane_rows: int, pitch: int, word: int) -> int:
-    """Largest G in {64..1} with G | rows, G | q, G | plane_rows, and a
-    G*pitch working set within the VMEM budget."""
-    for g in (64, 32, 16, 8, 4, 2, 1):
+    """Largest G in {64..8} with G | rows, G | q, G | plane_rows, and a
+    G*pitch working set within the VMEM budget; 0 when there is none."""
+    for g in (64, 32, 16, 8):
         if rows % g or q % g or (plane_rows % g if plane_rows else 0):
             continue
         if g * pitch * word <= VMEM_BUDGET_BYTES:
             return g
-    return 1
+    return 0
 
 
 def plan_geometry(
@@ -94,6 +116,8 @@ def plan_geometry(
     * the contiguous block does not straddle a pitch boundary
     * 3D: the plane stride is a whole number of pitches
     * one pitch row fits in VMEM
+    * an 8-aligned row group exists, or else one view plane holding the
+      object's rows of that plane fits in VMEM
     """
     if sb.ndims not in (2, 3):
         return None
@@ -120,7 +144,13 @@ def plan_geometry(
 
     g = _choose_group(c1, q, plane_rows, pitch, w)
     rows_needed = q + (c2 - 1) * plane_rows + c1
-    rows_padded = math.ceil(rows_needed / g) * g
+    if g:
+        rows_padded = math.ceil(rows_needed / g) * g
+    else:
+        view = plane_rows or rows_needed
+        if (q % view) + c1 > view or view * pitch * w > vmem_budget:
+            return None  # rows leave their plane, or a plane blows VMEM
+        rows_padded = math.ceil(rows_needed / view) * view
     return PackGeometry(
         word_bytes=w,
         lanes=lanes,

@@ -265,12 +265,76 @@ def _prep_words(b: jax.Array, geom: PackGeometry) -> jax.Array:
     return words.reshape(geom.rows_padded, geom.pitch)
 
 
+#: words per step of :func:`packed_bytes`
+_BYTES_CHUNK_WORDS = 4096
+
+
+def packed_bytes(packed: jax.Array) -> jax.Array:
+    """Flat bytes of a (planes, rows, lanes) packed word array, converted
+    ``_BYTES_CHUNK_WORDS`` words per loop step.  Turning narrow word rows
+    into flat bytes is a relayout on the TPU, and the TPU compiler's time
+    for it grows with the array: about 95 s for one 1 MiB object in one
+    piece (v5e, installed libtpu), about a second in chunks."""
+    rows = packed.reshape(-1, packed.shape[-1])
+    m, k = rows.shape
+    per = max(1, _BYTES_CHUNK_WORDS // k)
+    if m <= per:
+        return words_to_bytes(rows.reshape(-1))
+    nfull, tail = divmod(m, per)
+    step = per * k * rows.dtype.itemsize
+
+    def body(i, acc):
+        chunk = jax.lax.dynamic_slice_in_dim(rows, i * per, per)
+        return jax.lax.dynamic_update_slice(
+            acc, words_to_bytes(chunk.reshape(-1)), (i * step,)
+        )
+
+    out = jax.lax.fori_loop(
+        0, nfull, body, jnp.zeros((m * k * rows.dtype.itemsize,), jnp.uint8)
+    )
+    if tail:
+        out = jax.lax.dynamic_update_slice(
+            out, words_to_bytes(rows[nfull * per:].reshape(-1)),
+            (nfull * step,),
+        )
+    return out
+
+
+def packed_words(packed: jax.Array, w: int, shape) -> jax.Array:
+    """Inverse of :func:`packed_bytes`: flat packed bytes as a word array
+    of ``shape`` (..., lanes), in the same bounded loop steps.  The
+    barrier keeps the compiler from hoisting the byte-to-word relayout
+    above the slice that cut ``packed`` out of a wire buffer, which would
+    relayout the whole wire once per leaf."""
+    packed = jax.lax.optimization_barrier(packed)
+    k = shape[-1]
+    m = packed.shape[0] // (w * k)
+    per = max(1, _BYTES_CHUNK_WORDS // k)
+    if m <= per:
+        return as_words(packed, w).reshape(shape)
+    nfull, tail = divmod(m, per)
+    step = per * k * w
+
+    def body(i, acc):
+        chunk = jax.lax.dynamic_slice(packed, (i * step,), (step,))
+        return jax.lax.dynamic_update_slice_in_dim(
+            acc, as_words(chunk, w).reshape(per, k), i * per, axis=0
+        )
+
+    out = jax.lax.fori_loop(0, nfull, body, jnp.zeros((m, k), _UINT[w]))
+    if tail:
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, as_words(packed[nfull * step:], w).reshape(tail, k),
+            nfull * per, axis=0,
+        )
+    return out.reshape(shape)
+
+
 def run_pack_kernel(b: jax.Array, geom: PackGeometry, kernel, interpret: bool):
     """Drive a (src2d, geom, interpret) -> (planes, rows, lanes) pack
     kernel through the shared word-view prep, returning packed bytes."""
     src2d = _prep_words(b, geom)
-    out = kernel(src2d, geom, interpret=interpret)
-    return words_to_bytes(out.reshape(-1))
+    return packed_bytes(kernel(src2d, geom, interpret=interpret))
 
 
 def run_unpack_kernel(
@@ -282,8 +346,8 @@ def run_unpack_kernel(
     n = b.shape[0]
     covered = geom.rows_padded * geom.pitch * geom.word_bytes
     dst2d = _prep_words(b, geom)
-    pk3 = as_words(packed, geom.word_bytes).reshape(
-        geom.planes, geom.rows, geom.lanes
+    pk3 = packed_words(
+        packed, geom.word_bytes, (geom.planes, geom.rows, geom.lanes)
     )
     out2d = kernel(dst2d, pk3, geom, interpret=interpret)
     out_b = words_to_bytes(out2d.reshape(-1))
@@ -310,6 +374,22 @@ def _pack_one(
     return strat.pack_leaf(b, sb, geom, interpret)
 
 
+def _plane_words(buf: jax.Array, plan: _Plan, incount: int):
+    """``buf`` itself as the (planes, view_rows, pitch) word view of a 3D
+    plane-block geometry, when its own shape already is that view.  The
+    TPU stores a 3D array tiled over its last two dims, so flattening it
+    to bytes and back costs a relayout copy of the whole buffer."""
+    g = plan.geom
+    if (
+        incount != 1 or g is None or not g.plane_block or not g.plane_rows
+        or buf.ndim != 3 or buf.dtype == jnp.bool_
+        or buf.dtype.itemsize != g.word_bytes
+        or buf.shape[1:] != (g.view_rows, g.pitch)
+    ):
+        return None
+    return jax.lax.bitcast_convert_type(buf, _UINT[g.word_bytes])
+
+
 def pack(
     buf: jax.Array,
     ct: CommittedType,
@@ -323,6 +403,11 @@ def pack(
     if interpret is None:
         interpret = _interpret_default()
     plan = _plan(ct, incount)
+    view = _plane_words(buf, plan, incount)
+    if view is not None:
+        out = strat.pack_planes(view, plan.geom, interpret)
+        if out is not None:
+            return out
     b = byte_view(buf)
     if plan.kind is KernelKind.GENERIC or plan.sb is None:
         return refk.pack_ref(b, ct.block, incount, ct.extent)  # pragma: no cover
@@ -386,8 +471,18 @@ def unpack(
     if interpret is None:
         interpret = _interpret_default()
     plan = _plan(ct, incount)
-    b = byte_view(buf)
     packed = byte_view(packed)
+    view = _plane_words(buf, plan, incount)
+    if view is not None:
+        g = plan.geom
+        out = strat.unpack_planes(
+            view,
+            packed_words(packed, g.word_bytes, (g.planes, g.rows, g.lanes)),
+            g, interpret,
+        )
+        if out is not None:
+            return jax.lax.bitcast_convert_type(out, buf.dtype)
+    b = byte_view(buf)
     if plan.kind is KernelKind.GENERIC or plan.sb is None:  # pragma: no cover
         out = refk.unpack_ref(b, packed, ct.block, incount, ct.extent)
         return unbyte_view(out, buf.dtype, buf.shape)

@@ -11,12 +11,13 @@ implementations parameterized by W":
   (over-fetch factor pitch/lanes) — cheap when blocks are a large
   fraction of the pitch.
 
-* ``pack_dma``   — *strided descriptor kernel.*  The source stays in
-  HBM (memory_space=ANY) and each grid step issues one strided DMA for
-  exactly the bytes of a row-chunk of blocks.  No over-fetch, but the
-  copies are manually synchronized (single-buffered v1).  Preferred for
-  small blocks at large strides — the regime where the paper's Fig. 10
-  shows naive methods collapsing.
+* ``pack_dma``   — *DMA kernel.*  The source stays in HBM
+  (memory_space=ANY) and each grid step issues one DMA of the whole
+  pitch rows of a row-chunk of blocks, cutting the blocks out in VMEM.
+  TPU HBM is tiled (8, 128), so a DMA cannot fetch a narrower lane
+  window: it over-fetches like the row kernel, and differs from it in
+  being manually synchronized (single-buffered v1).  Needs an 8-aligned
+  row geometry (not ``PackGeometry.plane_block``).
 
 The runtime performance model (``repro.comm.perfmodel``) chooses between
 them, as the paper chooses between one-shot/device/staged.
@@ -43,12 +44,9 @@ __all__ = [
     "pack_ragged",
     "pack_compress_ragged",
     "choose_chunk",
+    "dma_steps",
+    "plane_view",
 ]
-
-# pinned-JAX compat: the memory-space enum was renamed
-# TPUMemorySpace -> MemorySpace in newer Pallas releases
-_MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-
 
 # ---------------------------------------------------------------------------
 # ragged wire assembly
@@ -103,55 +101,104 @@ def _pack_rows_kernel(src_ref, out_ref, *, r: int, lanes: int):
     out_ref[0] = src_ref[:, r : r + lanes]
 
 
+def _pack_plane_kernel(src_ref, out_ref, *, y0: int, rows: int, r: int,
+                       lanes: int):
+    # src_ref: (1, view_rows, pitch) one whole view plane
+    # out_ref: (1, rows, lanes) that plane's packed blocks
+    out_ref[0] = src_ref[0, y0 : y0 + rows, r : r + lanes]
+
+
+def plane_view(src2d: jax.Array, geom: PackGeometry) -> jax.Array:
+    """The (rows_padded, pitch) word view as (planes, view_rows, pitch)."""
+    return src2d.reshape(-1, geom.view_rows, geom.pitch)
+
+
 def pack_rows(src2d: jax.Array, geom: PackGeometry, interpret: bool = False):
-    """Pack via pitched BlockSpec row-groups.
+    """Pack via pitched BlockSpec row-groups (or whole view planes, see
+    :class:`PackGeometry`).
 
     ``src2d`` is the W-word view reshaped to (rows_padded, pitch).
     Returns the packed array of shape (planes, rows, lanes).
     """
+    out_shape = jax.ShapeDtypeStruct(
+        (geom.planes, geom.rows, geom.lanes), src2d.dtype
+    )
+    if geom.plane_block:
+        z0, y0 = divmod(geom.q, geom.view_rows)
+        return pl.pallas_call(
+            functools.partial(
+                _pack_plane_kernel, y0=y0, rows=geom.rows, r=geom.r,
+                lanes=geom.lanes,
+            ),
+            grid=geom.grid,
+            in_specs=[
+                pl.BlockSpec((1, geom.view_rows, geom.pitch),
+                             lambda p: (z0 + p, 0, 0))
+            ],
+            out_specs=pl.BlockSpec((1, geom.rows, geom.lanes),
+                                   lambda p: (p, 0, 0)),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(plane_view(src2d, geom))
+
     g = geom.group
     qb = geom.q // g
     prb = geom.plane_rows // g if geom.plane_rows else 0
-
     return pl.pallas_call(
         functools.partial(_pack_rows_kernel, r=geom.r, lanes=geom.lanes),
-        grid=(geom.planes, geom.rows // g),
+        grid=geom.grid,
         in_specs=[
             pl.BlockSpec((g, geom.pitch), lambda p, i: (qb + p * prb + i, 0))
         ],
         out_specs=pl.BlockSpec((1, g, geom.lanes), lambda p, i: (p, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (geom.planes, geom.rows, geom.lanes), src2d.dtype
-        ),
+        out_shape=out_shape,
         interpret=interpret,
     )(src2d)
 
 
 # ---------------------------------------------------------------------------
-# strided-descriptor DMA kernel
+# DMA kernel
 # ---------------------------------------------------------------------------
+#
+# TPU HBM is laid out in (8, 128) tiles and a DMA moves whole tiles, so a
+# copy can address neither a narrower lane window of a row nor a row
+# window that does not start on an 8-row boundary.  Each step DMAs whole
+# pitch rows of an 8-aligned row-chunk into fast memory and cuts the
+# blocks out there; plane-block geometries have no such chunks.
 
-def choose_chunk(rows: int, lanes: int, word: int, budget: int) -> int:
-    """Rows of blocks per DMA step: largest divisor of ``rows`` from a
-    pow2 ladder whose (chunk, lanes) scratch fits the VMEM budget."""
-    for c in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if rows % c == 0 and c * lanes * word <= budget:
+def choose_chunk(rows: int, width: int, word: int, budget: int) -> int:
+    """Rows per DMA step: largest divisor of ``rows`` from an 8-aligned
+    pow2 ladder whose (chunk, width) scratch fits the VMEM budget
+    (0 when none does)."""
+    for c in (512, 256, 128, 64, 32, 16, 8):
+        if rows % c == 0 and c * width * word <= budget:
             return c
-    return 1
+    return 0
 
 
-def _pack_dma_kernel(
-    src_ref, out_ref, scratch, sem, *, q, r, plane_rows, chunk, lanes
-):
-    p = pl.program_id(0)
-    ib = pl.program_id(1)
-    row0 = q + p * plane_rows + ib * chunk
-    cp = pltpu.make_async_copy(
-        src_ref.at[pl.ds(row0, chunk), pl.ds(r, lanes)], scratch, sem
-    )
+def dma_steps(geom: PackGeometry, vmem_budget: int):
+    """``(chunk, window)`` shared by both DMA kernels: each grid step
+    ``(p, i)`` moves the ``chunk`` full-pitch rows ``window(ref)`` of
+    plane ``p``.  Needs a row-group geometry (not ``plane_block``)."""
+    if geom.plane_block:
+        raise ValueError("the DMA kernels need an 8-aligned row geometry")
+    chunk = choose_chunk(geom.rows, geom.pitch, geom.word_bytes, vmem_budget)
+
+    def window(ref):
+        row0 = (
+            geom.q + pl.program_id(0) * geom.plane_rows
+            + pl.program_id(1) * chunk
+        )
+        return ref.at[pl.ds(pl.multiple_of(row0, 8), chunk)]
+
+    return chunk, window
+
+
+def _pack_dma_kernel(src_ref, out_ref, scratch, sem, *, window, r, lanes):
+    cp = pltpu.make_async_copy(window(src_ref), scratch, sem)
     cp.start()
     cp.wait()
-    out_ref[0] = scratch[...]
+    out_ref[0] = scratch[:, r : r + lanes]
 
 
 def pack_dma(
@@ -160,27 +207,21 @@ def pack_dma(
     vmem_budget: int,
     interpret: bool = False,
 ):
-    """Pack via one strided DMA per row-chunk; fetches exactly the block
-    bytes (no pitch over-fetch).  ``src2d`` as in :func:`pack_rows`."""
-    chunk = choose_chunk(geom.rows, geom.lanes, geom.word_bytes, vmem_budget)
-    kern = functools.partial(
-        _pack_dma_kernel,
-        q=geom.q,
-        r=geom.r,
-        plane_rows=geom.plane_rows,
-        chunk=chunk,
-        lanes=geom.lanes,
-    )
+    """Pack via one DMA of whole pitch rows per row-chunk (manually
+    synchronized, single-buffered).  ``src2d`` as in :func:`pack_rows`."""
+    chunk, window = dma_steps(geom, vmem_budget)
     return pl.pallas_call(
-        kern,
+        functools.partial(
+            _pack_dma_kernel, window=window, r=geom.r, lanes=geom.lanes
+        ),
         grid=(geom.planes, geom.rows // chunk),
-        in_specs=[pl.BlockSpec(memory_space=_MemorySpace.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
         out_specs=pl.BlockSpec((1, chunk, geom.lanes), lambda p, i: (p, i, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (geom.planes, geom.rows, geom.lanes), src2d.dtype
         ),
         scratch_shapes=[
-            pltpu.VMEM((chunk, geom.lanes), src2d.dtype),
+            pltpu.VMEM((chunk, geom.pitch), src2d.dtype),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,

@@ -9,9 +9,10 @@ Unpack writes *into* an existing buffer, so both kernels are in-place
   ranges to be disjoint (guaranteed for well-formed strided types where
   ``strides[2] >= counts[1]*strides[1]``; checked by the planner).
 
-* ``unpack_dma``  — the destination stays in HBM (ANY); each step copies
-  a packed row-chunk to VMEM scratch and issues one strided DMA into the
-  destination window.  Touches exactly the block bytes.
+* ``unpack_dma``  — the destination stays in HBM (ANY); each step DMAs
+  the whole pitch rows of a row-chunk into VMEM scratch, splices the
+  packed blocks in, and DMAs the rows back (TPU DMAs move whole (8, 128)
+  tiles, so a narrower window cannot be written).
 
 The paper notes unpack is slower than pack ("non-contiguous writes
 instead of non-contiguous reads"); the same asymmetry exists here —
@@ -28,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.geometry import PackGeometry
-from repro.kernels.pack import _MemorySpace, choose_chunk
+from repro.kernels.pack import dma_steps, plane_view
 
 __all__ = ["unpack_rows", "unpack_dma", "unpack_ragged", "decode_unpack_ragged"]
 
@@ -75,9 +76,17 @@ def decode_unpack_ragged(dst: jax.Array, wire: jax.Array, leaves) -> jax.Array:
 
 
 def _unpack_rows_kernel(dst_ref, pk_ref, out_ref, *, r: int, lanes: int):
-    # dst_ref/out_ref: (G, pitch); pk_ref: (1, G, lanes)
-    tmp = dst_ref[...]
-    out_ref[...] = tmp.at[:, r : r + lanes].set(pk_ref[0])
+    # dst_ref/out_ref: (G, pitch); pk_ref: (1, G, lanes).  Two ref
+    # stores, not a value-level .at[].set (Mosaic has no scatter).
+    out_ref[...] = dst_ref[...]
+    out_ref[:, r : r + lanes] = pk_ref[0]
+
+
+def _unpack_plane_kernel(dst_ref, pk_ref, out_ref, *, y0: int, rows: int,
+                         r: int, lanes: int):
+    # dst_ref/out_ref: (1, view_rows, pitch); pk_ref: (1, rows, lanes)
+    out_ref[...] = dst_ref[...]
+    out_ref[0, y0 : y0 + rows, r : r + lanes] = pk_ref[0]
 
 
 def unpack_rows(
@@ -86,11 +95,34 @@ def unpack_rows(
     geom: PackGeometry,
     interpret: bool = False,
 ):
-    """In-place splice of packed blocks into full-pitch row-groups.
+    """In-place splice of packed blocks into full-pitch row-groups (or
+    whole view planes, see :class:`PackGeometry`).
 
     ``dst2d``: (rows_padded, pitch) word view of the destination buffer.
     ``packed3d``: (planes, rows, lanes).  Returns the updated 2D view.
     """
+    if geom.plane_block:
+        z0, y0 = divmod(geom.q, geom.view_rows)
+        plane = pl.BlockSpec((1, geom.view_rows, geom.pitch),
+                             lambda p: (z0 + p, 0, 0))
+        dst3d = plane_view(dst2d, geom)
+        out = pl.pallas_call(
+            functools.partial(
+                _unpack_plane_kernel, y0=y0, rows=geom.rows, r=geom.r,
+                lanes=geom.lanes,
+            ),
+            grid=geom.grid,
+            in_specs=[
+                plane,
+                pl.BlockSpec((1, geom.rows, geom.lanes), lambda p: (p, 0, 0)),
+            ],
+            out_specs=plane,
+            out_shape=jax.ShapeDtypeStruct(dst3d.shape, dst3d.dtype),
+            input_output_aliases={0: 0},
+            interpret=interpret,
+        )(dst3d, packed3d)
+        return out.reshape(dst2d.shape)
+
     g = geom.group
     qb = geom.q // g
     prb = geom.plane_rows // g if geom.plane_rows else 0
@@ -98,7 +130,7 @@ def unpack_rows(
 
     return pl.pallas_call(
         functools.partial(_unpack_rows_kernel, r=geom.r, lanes=geom.lanes),
-        grid=(geom.planes, geom.rows // g),
+        grid=geom.grid,
         in_specs=[
             pl.BlockSpec((g, geom.pitch), row_idx),
             pl.BlockSpec((1, g, geom.lanes), lambda p, i: (p, i, 0)),
@@ -110,17 +142,15 @@ def unpack_rows(
     )(dst2d, packed3d)
 
 
-def _unpack_dma_kernel(
-    pk_ref, dst_ref, out_ref, scratch, sem, *, q, r, plane_rows, chunk, lanes
-):
+def _unpack_dma_kernel(pk_ref, dst_ref, out_ref, scratch, sem, *, window,
+                       r, lanes):
     del dst_ref  # aliased with out_ref; present only for donation
-    p = pl.program_id(0)
-    ib = pl.program_id(1)
-    row0 = q + p * plane_rows + ib * chunk
-    scratch[...] = pk_ref[0]
-    cp = pltpu.make_async_copy(
-        scratch, out_ref.at[pl.ds(row0, chunk), pl.ds(r, lanes)], sem
-    )
+    rows = window(out_ref)
+    cp = pltpu.make_async_copy(rows, scratch, sem)
+    cp.start()
+    cp.wait()
+    scratch[:, r : r + lanes] = pk_ref[0]
+    cp = pltpu.make_async_copy(scratch, rows, sem)
     cp.start()
     cp.wait()
 
@@ -132,28 +162,24 @@ def unpack_dma(
     vmem_budget: int,
     interpret: bool = False,
 ):
-    """In-place strided-DMA scatter of packed blocks (no pitch traffic)."""
-    chunk = choose_chunk(geom.rows, geom.lanes, geom.word_bytes, vmem_budget)
-    kern = functools.partial(
-        _unpack_dma_kernel,
-        q=geom.q,
-        r=geom.r,
-        plane_rows=geom.plane_rows,
-        chunk=chunk,
-        lanes=geom.lanes,
-    )
+    """In-place scatter of packed blocks by read-modify-write DMAs of
+    whole pitch rows, one step at a time — so steps whose rows overlap
+    (interleaved planes) never lose an update."""
+    chunk, window = dma_steps(geom, vmem_budget)
     return pl.pallas_call(
-        kern,
+        functools.partial(
+            _unpack_dma_kernel, window=window, r=geom.r, lanes=geom.lanes
+        ),
         grid=(geom.planes, geom.rows // chunk),
         in_specs=[
             pl.BlockSpec((1, chunk, geom.lanes), lambda p, i: (p, i, 0)),
-            pl.BlockSpec(memory_space=_MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=_MemorySpace.ANY),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         out_shape=jax.ShapeDtypeStruct(dst2d.shape, dst2d.dtype),
         input_output_aliases={1: 0},
         scratch_shapes=[
-            pltpu.VMEM((chunk, geom.lanes), dst2d.dtype),
+            pltpu.VMEM((chunk, geom.pitch), dst2d.dtype),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,

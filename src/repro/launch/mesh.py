@@ -6,7 +6,8 @@ importing this module never touches jax device state.
 
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "batch_axes"]
 
@@ -20,14 +21,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small mesh for CI: same axis names, tiny shapes."""
     if pod:
-        return make_mesh((pod, data, model), ("pod", "data", "model"))
-    return make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (the installed JAX
+    defaults to ``Explicit``, which the sharding rules here do not use)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple:

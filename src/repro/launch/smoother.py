@@ -31,13 +31,12 @@ from __future__ import annotations
 
 import argparse
 from dataclasses import dataclass
-from typing import Tuple, Union
+from typing import Optional, Tuple, Union
 
 import numpy as np
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.comm.api import as_communicator
 from repro.halo.program import (
@@ -47,7 +46,10 @@ from repro.halo.program import (
 )
 from repro.halo.stencil import STENCIL26, StencilOp
 
-__all__ = ["CYCLES", "SmootherReport", "run_smoother", "smoother_cycle"]
+__all__ = [
+    "CYCLES", "SmootherReport", "initial_state", "run_smoother",
+    "smoother_cycle",
+]
 
 #: the in-launch cycles by name (argparse choices on every driver)
 CYCLES: Tuple[str, ...] = ("smooth", "predictor-corrector")
@@ -71,6 +73,7 @@ class SmootherReport:
     iterations: int
     checksum: float      # interior sum after the run (reproducibility probe)
     decision_recorded: bool  # a program/s=N row exists in the decisions
+    field: jax.Array     # the sharded (ranks*az, ay, ax) state after the run
 
     @property
     def summary(self) -> str:
@@ -85,6 +88,22 @@ class SmootherReport:
         )
 
 
+def initial_state(program: HaloProgram, seed: int = 0) -> np.ndarray:
+    """The smoother's starting field: standard-normal interiors drawn
+    from ``seed``, zero halo shells, as the (ranks*az, ay, ax) global
+    array the program step takes."""
+    R = program.spec.nranks
+    nz, ny, nx = program.spec.interior
+    rz, ry, rx = program.spec.radii
+    az, ay, ax = program.spec.alloc
+    state = np.zeros((R, az, ay, ax), np.float32)
+    state[:, rz:rz + nz, ry:ry + ny, rx:rx + nx] = (
+        np.random.default_rng(seed).normal(size=(R, nz, ny, nx))
+        .astype(np.float32)
+    )
+    return state.reshape(R * az, ay, ax)
+
+
 def run_smoother(
     comm,
     iters: int = 1,
@@ -95,6 +114,7 @@ def run_smoother(
     seed: int = 0,
     devices=None,
     overlap: str = "off",
+    schedule_policy: Optional[str] = None,
 ) -> SmootherReport:
     """Smooth a sharded 3D field with one fused deep-halo program.
 
@@ -113,6 +133,11 @@ def run_smoother(
     the core/face/edge/corner region scheduler), or ``"auto"`` (the
     model picks and pins an ``overlap/mode=...`` decision).  All modes
     are bit-identical; the checksum must not move.
+
+    ``schedule_policy`` is forwarded to the wire planner (``None``: the
+    communicator's model-priced default; ``"exact"``: the byte-exact
+    ladder, which takes the native ragged collective wherever the
+    backend has it).
     """
     comm = as_communicator(comm)
     if overlap not in ("off", "monolithic", "region", "auto"):
@@ -125,7 +150,8 @@ def run_smoother(
     grid = (R, 1, 1)
     ops = smoother_cycle(cycle)
     program = build_halo_program(
-        grid, interior, comm, ops=ops, steps=halo_steps
+        grid, interior, comm, ops=ops, steps=halo_steps,
+        schedule_policy=schedule_policy,
     )
     mesh = Mesh(np.array(devs), (axis_name,))
     step = make_program_step(
@@ -136,12 +162,9 @@ def run_smoother(
     nz, ny, nx = interior
     rz, ry, rx = program.spec.radii
     az, ay, ax = program.spec.alloc
-    rng = np.random.default_rng(seed)
-    state = np.zeros((R, az, ay, ax), np.float32)
-    state[:, rz:rz + nz, ry:ry + ny, rx:rx + nx] = rng.normal(
-        size=(R, nz, ny, nx)
-    ).astype(np.float32)
-    x = jnp.asarray(state.reshape(R * az, ay, ax))
+    x = jax.device_put(
+        initial_state(program, seed), NamedSharding(mesh, P(axis_name))
+    )
     telemetry = getattr(comm, "telemetry", None)
     tracer = getattr(comm, "tracer", None)
     if tracer is not None and not getattr(tracer, "enabled", False):
@@ -215,6 +238,7 @@ def run_smoother(
         iterations=iters,
         checksum=checksum,
         decision_recorded=recorded,
+        field=x,
     )
 
 
@@ -262,6 +286,10 @@ def main() -> None:
                     help="exit 1 when the drift audit flags any decision "
                          "— the CI drift gate")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from repro.halo.program import parse_halo_steps
     from repro.measure.production import production_communicator

@@ -224,6 +224,10 @@ def main() -> None:
                          "with `python -m repro.obs summary`)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from repro.halo.program import parse_halo_steps
 
     halo_steps = parse_halo_steps(args.halo_steps)

@@ -323,7 +323,7 @@ def measure_wire_table(
     still prices collective dispatch).  Rows are (log2_bytes, sec)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     n = len(devs)
@@ -359,7 +359,7 @@ def measure_wire_tables(
     """
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     if axes is None:
@@ -418,7 +418,7 @@ def measure_link_class_tables(
     """
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     n = topology.nranks

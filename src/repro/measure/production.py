@@ -36,6 +36,25 @@ __all__ = ["DECISIONS_FILENAME", "production_communicator"]
 DECISIONS_FILENAME = "decisions.json"
 
 
+#: ``device_kind`` of the chip the analytic ``TPU_V5E`` table describes
+V5E_DEVICE_KIND = "TPU v5 lite"
+
+
+def _analytic_params() -> SystemParams:
+    """``TPU_V5E`` when the devices are v5e chips; an error elsewhere,
+    where the analytic table would price a device it does not describe."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    if kind != V5E_DEVICE_KIND:
+        raise RuntimeError(
+            f"no stored calibration for this system and the analytic "
+            f"table describes a {V5E_DEVICE_KIND!r}, not {kind!r}: "
+            "calibrate (calibrate=True) or pass params="
+        )
+    return TPU_V5E
+
+
 def production_communicator(
     cache_dir: Optional[Union[str, Path]] = None,
     axis_name: Optional[str] = None,
@@ -59,7 +78,8 @@ def production_communicator(
     calibrate: when True (default), a missing calibration for this
         system fingerprint is measured once and persisted
         (``load_or_calibrate``); when False, a missing calibration falls
-        back to the analytic table — nothing slow happens.
+        back to the analytic ``TPU_V5E`` table on a v5e and raises
+        anywhere else — nothing slow happens.
     reduced: grid size for a fresh calibration; defaults to reduced
         everywhere but on a real TPU backend.
     params: explicit SystemParams override (skips the store entirely).
@@ -108,7 +128,7 @@ def production_communicator(
                 reduced = jax.default_backend() != "tpu"
             params = store.load_or_calibrate(reduced=reduced)
         else:
-            params = store.load() or TPU_V5E
+            params = store.load() or _analytic_params()
     decisions_path = store.root / DECISIONS_FILENAME
     decisions = DecisionCache.load(decisions_path)
     tel = None

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.distributed.sharding import constrain
 
 __all__ = [

@@ -29,7 +29,7 @@ independently.  This module records that decomposition as it happens:
   exactly like the telemetry probe: a ``perf_counter`` pair inside a
   ``jit``/``shard_map`` trace measures tracing, not transfer, so
   :meth:`Tracer.span` records nothing unless
-  ``jax.core.trace_state_clean()`` says execution is eager (callers
+  ``jax.core.trace_ctx.is_top_level()`` says execution is eager (callers
   additionally skip on tracer *operands*, same as telemetry).  Eager
   paths time phases with ``block_until_ready`` at each span exit;
   compiled (fused) iterations are recorded after the fact by
@@ -73,10 +73,7 @@ DEFAULT_MAX_SPANS = 200_000
 
 def _trace_state_clean() -> bool:
     """True when no jax trace is being staged (eager execution)."""
-    fn = getattr(jax.core, "trace_state_clean", None)
-    if fn is None:  # pragma: no cover - very old jax
-        return True
-    return bool(fn())
+    return jax.core.trace_ctx.is_top_level()
 
 
 @dataclass(slots=True)

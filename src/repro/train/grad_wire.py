@@ -134,7 +134,7 @@ class GradWire:
     def _build(self, grads):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
 
         axis = self.comm.axis_name or "data"
         devs = jax.devices()

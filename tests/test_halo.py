@@ -86,7 +86,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.comm import Communicator
-from repro.compat import shard_map
+from jax import shard_map
 from repro.halo import HaloSpec, halo_exchange, make_halo_types, stencil_iterations
 
 grid = (2, 2, 2)

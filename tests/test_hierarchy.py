@@ -576,7 +576,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import (
     Communicator, FixedPolicy, Topology, collective_payload_bytes,
     reschedule,

@@ -126,6 +126,27 @@ def test_pack_3d_halo_faces(named):
         check_roundtrip(dt)
 
 
+@pytest.mark.parametrize("strat", ALL_STRATEGIES)
+def test_pack_3d_regions_of_own_shape_buffer(strat):
+    """Halo regions of a 3D float array: plane-block geometries whose
+    planes are the array's own trailing dims take the no-flatten path
+    (the buffer's own shape as the word view); both paths must equal the
+    byte oracle."""
+    alloc = (10, 9, 12)  # (z, y, x): 9-row planes admit no 8-row group
+    buf = jnp.asarray(RNG.normal(size=alloc).astype(np.float32))
+    dst0 = jnp.asarray(RNG.normal(size=alloc).astype(np.float32))
+    for sub, start in (((2, 5, 3), (0, 2, 9)), ((3, 2, 12), (7, 0, 0))):
+        ct = REG.commit(Subarray(alloc, sub, start, FLOAT, order="C"))
+        want = np.asarray(pack_ref(byte_view(buf), ct.block))
+        got = pack(buf, ct, strategy=strat)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        out = unpack(dst0, got, ct, strategy=strat)
+        want_dst = unpack_ref(byte_view(dst0), jnp.asarray(want), ct.block)
+        np.testing.assert_array_equal(
+            np.asarray(byte_view(out)), np.asarray(want_dst)
+        )
+
+
 @pytest.mark.parametrize("incount", [1, 2, 3])
 def test_incount(incount):
     check_roundtrip(Vector(6, 20, 50, BYTE), incount=incount)
@@ -158,7 +179,14 @@ def test_geometry_planner_properties():
     assert g.word_bytes == 4
     assert g.lanes == 25 and g.pitch == 128
     assert g.rows == 13 and g.planes == 1
-    assert g.rows % g.group == 0
+    # 13 rows admit no 8-aligned row group (the TPU sublane tile): the
+    # kernels move the whole 2D view as one plane block
+    assert g.plane_block and g.view_rows == 13
+    assert g.view_rows * g.pitch * g.word_bytes <= VMEM_BUDGET_BYTES
+    assert g.overfetch == pytest.approx(128 / 25)
+    g = plan_geometry(REG.commit(Vector(64, 25, 128, FLOAT)).block)
+    assert not g.plane_block
+    assert g.group % 8 == 0 and g.rows % g.group == 0
     assert g.group * g.pitch * g.word_bytes <= VMEM_BUDGET_BYTES
     assert g.overfetch == pytest.approx(128 / 25)
 

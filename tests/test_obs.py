@@ -82,11 +82,31 @@ class TestTracer:
         assert len(tr) == 0
         assert not any(s.name == "should-not-record" for s in tr.spans)
 
+    @pytest.mark.parametrize("stage", [
+        lambda f, x: jax.block_until_ready(jax.jit(f)(x)),
+        lambda f, x: jax.make_jaxpr(f)(x),
+        jax.eval_shape,
+    ], ids=["jit", "make_jaxpr", "eval_shape"])
+    def test_inactive_while_tracing(self, stage):
+        # the eager check reads the installed JAX's trace context: every
+        # way of staging a function must switch the tracer off
+        tr = Tracer()
+        seen = []
+
+        def f(x):
+            seen.append(tr.active)
+            return x + 1
+
+        assert tr.active
+        stage(f, jnp.zeros(4))
+        assert seen == [False]
+        assert tr.active
+
     def test_no_spans_inside_shard_map(self):
         import numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
 
         tr = Tracer()
         mesh = Mesh(np.array(jax.devices()[:1]), ("x",))

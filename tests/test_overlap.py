@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator
 from repro.halo import (
     HaloSpec,
@@ -161,7 +161,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator
 from repro.halo import (HaloSpec, halo_exchange, make_halo_types,
                         overlapped_stencil_iteration, stencil_steps)

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import has_ragged_all_to_all, shard_map
+from jax import shard_map
 from repro.comm import (
     Communicator,
     FixedPolicy,
@@ -24,6 +24,7 @@ from repro.comm import (
     Int8Wire,
     PerfModel,
     SystemParams,
+    TPU_V5E,
     collective_payload_bytes,
     reschedule,
 )
@@ -209,7 +210,7 @@ class TestBuildProgram:
 
         before = get_default_halo_steps()
         try:
-            comm, _ = production_communicator(tmp_path, calibrate=False,
+            comm, _ = production_communicator(tmp_path, params=TPU_V5E,
                                               halo_steps=2)
             assert get_default_halo_steps() == 2
             prog = build_halo_program((2, 2, 2), (6, 5, 4), comm)
@@ -886,7 +887,7 @@ print("RAGGED_NATIVE_OK")
 # plans schedule=varlen on a fused layout and the traced exchange is
 # ONE ragged_all_to_all moving exactly the stream bytes
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import Subarray, FLOAT
 
 vcomm = Communicator(axis_name="ranks")
@@ -916,9 +917,11 @@ print("VARLEN_NATIVE_OK")
 
 @pytest.mark.slow
 @pytest.mark.skipif(
-    not has_ragged_all_to_all(),
-    reason="needs lax.ragged_all_to_all (JAX >= 0.5; the pinned 0.4.37 "
-           "lowers the ragged schedule to grouped ppermutes instead)",
+    jax.default_backend() != "tpu",
+    reason="needs a backend that runs lax.ragged_all_to_all natively "
+           "(TPU); XLA:CPU has no emitter for it, so CPU plans take the "
+           "grouped schedule (tests/test_tpu_compile.py compiles the "
+           "native exchange for a described v5e instead)",
 )
 def test_native_ragged_schedule_end_to_end():
     out = run_with_devices(RAGGED_NATIVE_CODE, ndev=8)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import ClassRequest, Communicator, NeighborRequest
 from repro.halo import (
     DIRECTIONS,
@@ -359,7 +359,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator
 from repro.halo import (HaloSpec, halo_exchange, make_halo_plan,
                         make_halo_types, overlapped_stencil_iteration,

@@ -8,11 +8,11 @@ CODE = r"""
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_test_mesh
 from repro.distributed.sharding import DEFAULT_RULES, use_rules
 from repro.models.layers import ring_update, ring_update_stacked
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh(data=2, model=4)
 B, S, KV, HD = 4, 16, 2, 8
 L = 3
 

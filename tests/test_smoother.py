@@ -10,6 +10,7 @@ Decision row -> pinned, checksum-identical rerun.
 import numpy as np
 import pytest
 
+from repro.comm import TPU_V5E
 from repro.halo import STENCIL26, get_default_halo_steps, set_default_halo_steps
 from repro.launch.smoother import CYCLES, run_smoother, smoother_cycle
 from repro.measure.production import production_communicator
@@ -31,7 +32,7 @@ class TestRunSmoother:
         before = get_default_halo_steps()
         try:
             comm, save = production_communicator(
-                tmp_path, axis_name="data", calibrate=False, halo_steps="auto"
+                tmp_path, axis_name="data", params=TPU_V5E, halo_steps="auto"
             )
             report = run_smoother(comm, iters=1, interior=(8, 8, 8),
                                   cycle="predictor-corrector")
@@ -47,7 +48,7 @@ class TestRunSmoother:
             # "the rerun": a fresh production communicator over the same
             # store pins the depth and reproduces the field bit-exactly
             comm2, _ = production_communicator(
-                tmp_path, axis_name="data", calibrate=False, halo_steps="auto"
+                tmp_path, axis_name="data", params=TPU_V5E, halo_steps="auto"
             )
             report2 = run_smoother(comm2, iters=1, interior=(8, 8, 8),
                                    cycle="predictor-corrector")
@@ -62,13 +63,23 @@ class TestRunSmoother:
         before = get_default_halo_steps()
         try:
             comm, _ = production_communicator(
-                tmp_path, axis_name="data", calibrate=False, halo_steps=1
+                tmp_path, axis_name="data", params=TPU_V5E, halo_steps=1
             )
             report = run_smoother(comm, iters=2, interior=(6, 6, 6),
-                                  cycle="smooth")
+                                  cycle="smooth", schedule_policy="exact")
             assert report.program.steps == 1
             assert report.iterations == 2
             assert "smoother:" in report.summary
             assert "exchanges/cycle=1.00" in report.summary
+            # the exact ladder on CPU devices: never the native schedule
+            assert report.program.plan.wire.schedule != "ragged"
+            # the returned field is the state the checksum summed
+            spec = report.program.spec
+            (rz, ry, rx), (nz, ny, nx) = spec.radii, spec.interior
+            field = np.asarray(report.field)
+            assert field.shape == (spec.nranks * spec.alloc[0],) + spec.alloc[1:]
+            assert float(
+                field[rz:rz + nz, ry:ry + ny, rx:rx + nx].sum()
+            ) == report.checksum
         finally:
             set_default_halo_steps(before)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import (
     Communicator,
     FixedPolicy,
@@ -29,7 +29,7 @@ from repro.comm import (
     default_registry,
 )
 from repro.comm.api import ROWS
-from repro.comm.wireplan import plan_wire
+from repro.comm.wireplan import has_ragged_all_to_all, plan_wire
 from repro.core import BYTE, FLOAT, Subarray, TypeRegistry, Vector, WireSegment
 from repro.halo import HaloSpec, make_halo_plan
 from repro.kernels.pack import pack_ragged
@@ -367,7 +367,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.comm import Communicator, FixedPolicy, collective_payload_bytes
 from repro.halo import HaloSpec, halo_exchange, make_halo_plan
 from repro.halo.exchange import DIRECTIONS
@@ -630,10 +630,19 @@ class TestProductionCommunicator:
         assert comm2.select(comm2.commit(dt)).name == first
         assert dc2.pinned_hits >= 1
 
-    def test_no_calibrate_falls_back_to_analytic(self, tmp_path):
-        from repro.measure.production import production_communicator
+    def test_no_calibrate_falls_back_to_analytic(self, tmp_path, monkeypatch):
+        from repro.measure import production
 
-        comm, _ = production_communicator(tmp_path, calibrate=False)
+        # the analytic table describes a v5e: it stands in for a missing
+        # calibration there, and nowhere else
+        with pytest.raises(RuntimeError, match="TPU v5 lite"):
+            production.production_communicator(tmp_path, calibrate=False)
+
+        class V5e:
+            device_kind = production.V5E_DEVICE_KIND
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [V5e()])
+        comm, _ = production.production_communicator(tmp_path, calibrate=False)
         assert comm.model.params.name == TPU_V5E.name
 
     def test_train_loop_reports_comm_stats(self, tmp_path):
@@ -649,3 +658,16 @@ class TestProductionCommunicator:
         out = train(cfg, steps=1, seq_len=8, global_batch=2,
                     ckpt_dir=str(tmp_path / "ckpt"), comm=comm)
         assert out["comm_stats"]["wire_ops"] == comm.wire_ops
+
+
+def test_native_gate_follows_the_backend():
+    """XLA:CPU has no ragged-all-to-all emitter, so on CPU devices the
+    planner must not pick the native schedule; asked for it, it does."""
+    assert not has_ragged_all_to_all()
+    assert not has_ragged_all_to_all(jax.devices())
+    # two delta classes of unequal size on 2 ranks: fused, but a uniform
+    # collective would pad, so the exact ladder is grouped unless native
+    sizes = (8, 16)
+    perms = (((0, 1), (1, 0)), ((0, 0), (1, 1)))
+    assert plan_wire(sizes, perms).schedule == "grouped"
+    assert plan_wire(sizes, perms, native=True).schedule == "ragged"
