@@ -83,3 +83,26 @@ class TestRunSmoother:
             ) == report.checksum
         finally:
             set_default_halo_steps(before)
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The entry points' compile cache follows JAX_COMPILATION_CACHE_DIR
+    and is otherwise one fixed directory inside the checkout."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+        assert compile_cache.use_compile_cache() == "/elsewhere/jax"
+        assert jax.config.jax_compilation_cache_dir == old  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        repo = Path(__file__).resolve().parents[1]
+        assert path == str(repo / ".cache" / "jax")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
