@@ -13,7 +13,8 @@ halo step), so the observability layer is held to the same standard as
 everything else it observes.  ``--assert-telemetry-overhead`` gates it
 at <2%.  ``--trace-overhead`` / ``--assert-trace-overhead`` do the same
 for the :mod:`repro.obs` tracer: the per-iteration cost of recording a
-compiled iteration's attributed span tree, held to the SAME budget.
+compiled iteration's measured span (with its profiler annotation), held
+to the SAME budget.
 """
 
 from __future__ import annotations
@@ -109,16 +110,16 @@ def trace_overhead(iters: int = 30) -> float:
     exchange loop iteration — the span-recording analog of
     :func:`telemetry_overhead`, held to the same budget.
 
-    A compiled deep-halo iteration records its whole span tree through
-    ONE :func:`repro.obs.trace.attribute_program_iteration` call (the
-    launch layer's per-iteration tracer hook), so that call's host cost
-    *is* the probe price; it is measured directly against the loop's
-    observed iteration time, like the telemetry probe.
+    A compiled deep-halo iteration is recorded as ONE
+    ``program_iteration`` span (:meth:`repro.obs.trace.Tracer.span`,
+    which also opens a ``jax.profiler.TraceAnnotation``), so that span's
+    host cost *is* the probe price; it is measured directly against the
+    loop's observed iteration time, like the telemetry probe.
     """
     from repro.comm.api import Communicator
-    from repro.fleet import ExchangeTelemetry, predict_program_phases
+    from repro.fleet import ExchangeTelemetry
     from repro.launch.smoother import run_smoother
-    from repro.obs.trace import Tracer, attribute_program_iteration
+    from repro.obs.trace import Tracer
 
     tel = ExchangeTelemetry()
     comm = Communicator(
@@ -131,18 +132,23 @@ def trace_overhead(iters: int = 30) -> float:
     assert agg is not None and agg.count == iters
     t_iter = agg.mean
     tracer = Tracer()
-    phases = predict_program_phases(report.program, comm.model)
-    t_probe = time_host_us(
-        lambda: attribute_program_iteration(
-            tracer, report.program, 0.0, t_iter, phases
-        ),
-        iters=500,
-    ) * 1e-6
+    program = report.program
+
+    def probe() -> None:
+        with tracer.span(
+            "program_iteration", fingerprint=program.fingerprint,
+            strategy=f"program/s={program.steps}", steps=program.steps,
+            cycle_len=program.cycle_len, pinned=bool(program.pinned),
+            pred=t_iter, iteration=0,
+        ):
+            pass
+
+    t_probe = time_host_us(probe, iters=500) * 1e-6
     overhead = t_probe / t_iter
     emit("measure/trace/exchange-iter", t_iter * 1e6,
          f"iters={iters};pinned={report.program.pinned}")
     emit("measure/trace/probe-call", t_probe * 1e6,
-         "attribute_program_iteration()")
+         "Tracer.span()")
     emit("measure/trace/overhead-pct", overhead * 100.0,
          f"budget={TELEMETRY_OVERHEAD_BUDGET * 100:.0f}%")
     return overhead

@@ -72,6 +72,7 @@ from repro.kernels.unpack import (
     unpack_ragged,
     unpack_rows,
 )
+from repro.obs.trace import scope
 from repro.comm.perfmodel import (
     PerfModel,
     StrategyEstimate,
@@ -242,6 +243,7 @@ class Strategy:
             buf, packed, ct, incount=incount, strategy=self, interpret=interpret
         )
 
+    @scope("unpack")
     def unpack_wire(
         self,
         comm: "Communicator",
@@ -436,6 +438,7 @@ class XlaBlocks(Strategy):
         return view
 
 
+@scope("wire")
 def _ragged_exchange(
     wire: jax.Array, plan: WirePlan, sizes: Sequence[int], axis: str
 ) -> List[jax.Array]:
@@ -581,6 +584,7 @@ class Bounding(Strategy):
             wire_bytes=nbytes,
         )
 
+    @scope("pack")
     def pack(self, buf, ct, incount=1, interpret=None):
         sb = ct.block
         if sb is None:
@@ -588,6 +592,7 @@ class Bounding(Strategy):
         ext = self.wire_bytes(ct, incount)
         return lax.dynamic_slice(ops.byte_view(buf), (sb.start,), (ext,))
 
+    @scope("unpack")
     def unpack_wire(self, comm, dst, wire, recv_ct, send_ct=None, incount=1):
         # extract member bytes from the received window: same geometry as
         # the send type, rebased to start 0
@@ -1001,8 +1006,8 @@ class Communicator:
         at each phase boundary (the decision signature and the model's
         per-phase predictions ride as span attributes); inside a jax
         trace nothing records, and fused compiled iterations are
-        attributed from the launch layer instead
-        (:func:`repro.obs.trace.attribute_program_iteration`).
+        timed whole by the launch layer and split by the ``tempi.*``
+        named scopes in a profiler trace (:func:`repro.obs.trace.scope`).
     topology: optional :class:`repro.comm.topology.Topology` — the
         rank -> node map of a two-level machine.  Wire plans pick up
         link-class annotations, pricing charges the slow tier per
@@ -1122,7 +1127,8 @@ class Communicator:
             est = s.plan(self.model, ct, incount)
             self.telemetry.register(ct.fingerprint, est.total, s.name)
         payload = s.pack(buf, ct, incount)
-        wire = lax.ppermute(payload, axis, list(perm))
+        with scope("wire"):
+            wire = lax.ppermute(payload, axis, list(perm))
         self.wire_ops += 1
         self.wire_payload_bytes += seg.nbytes
         return SendRequest(wire, s, ct, incount, segment=seg)
@@ -1198,7 +1204,8 @@ class Communicator:
                 jax.block_until_ready(payload)
             with self.tracer.span("wire", pred=est.t_link,
                                   wire_bytes=seg.nbytes):
-                wire = lax.ppermute(payload, axis, list(perm))
+                with scope("wire"):
+                    wire = lax.ppermute(payload, axis, list(perm))
                 jax.block_until_ready(wire)
             self.wire_ops += 1
             self.wire_payload_bytes += seg.nbytes
@@ -1353,6 +1360,7 @@ class Communicator:
             )
         return strats, plan
 
+    @scope("wire")
     def _issue_wire(
         self, wire: jax.Array, plan: WirePlan, axis: str
     ) -> List[jax.Array]:
@@ -1753,7 +1761,8 @@ class Communicator:
         axis = self._axis(axis_name)
         packed = self.pack(buf, ct, incount)
         self.wire_ops += 1
-        return lax.all_gather(packed, axis)
+        with scope("wire"):
+            return lax.all_gather(packed, axis)
 
     def all_to_all_packed(
         self,
@@ -1770,9 +1779,11 @@ class Communicator:
         if len(sizes) != 1:
             raise ValueError("all_to_all_packed needs equal-size segments")
         parts = [self.pack(buf, ct) for ct in cts]
-        sendbuf = jnp.stack(parts)  # (npeers, seg)
         self.wire_ops += 1
-        return lax.all_to_all(sendbuf, axis, split_axis=0, concat_axis=0)
+        with scope("wire"):
+            sendbuf = jnp.stack(parts)  # (npeers, seg)
+            return lax.all_to_all(sendbuf, axis, split_axis=0,
+                                  concat_axis=0)
 
     # ------------------------------------------------------------------
     def stats(self) -> Dict[str, int]:

@@ -37,6 +37,7 @@ from jax import lax
 from repro.comm.api import Strategy
 from repro.core.commit import CommittedType
 from repro.kernels import ops
+from repro.obs.trace import scope
 
 __all__ = [
     "Int8Wire",
@@ -133,6 +134,7 @@ class Int8Wire(Strategy):
         ).reshape(-1)
         return jnp.concatenate([header, ops.byte_view(q)])
 
+    @scope("pack")
     def pack(self, buf, ct, incount: int = 1, interpret: Optional[bool] = None):
         return self.encode_wire(
             ops.pack(buf, ct, incount=incount, interpret=interpret)
@@ -160,6 +162,7 @@ class Int8Wire(Strategy):
             f = q.astype(jnp.float32) * expand
         return lax.bitcast_convert_type(f.reshape(-1, 1), jnp.uint8).reshape(-1)
 
+    @scope("unpack")
     def unpack_wire(self, comm, dst, wire, recv_ct, send_ct=None, incount=1):
         member = self.decode_wire(wire, recv_ct.size * incount)
         u = comm.select(recv_ct, incount, wire=False)
@@ -331,6 +334,7 @@ class RleWire(Strategy):
         ).reshape(-1)
         return jnp.concatenate([header, body])
 
+    @scope("pack")
     def pack(self, buf, ct, incount: int = 1, interpret: Optional[bool] = None):
         return self.encode_wire(
             ops.pack(buf, ct, incount=incount, interpret=interpret)
@@ -370,6 +374,7 @@ class RleWire(Strategy):
         )
         return jnp.where(header[0] == 1, decoded, body)
 
+    @scope("unpack")
     def unpack_wire(self, comm, dst, wire, recv_ct, send_ct=None, incount=1):
         member = self.decode_wire(wire, recv_ct.size * incount)
         u = comm.select(recv_ct, incount, wire=False)

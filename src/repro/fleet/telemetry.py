@@ -303,9 +303,9 @@ def predict_program_phases(program, model) -> Dict[str, float]:
     the redundant ghost-shell compute the estimate prices *plus* the
     interior compute it deliberately excludes (every candidate depth
     pays the interior equally — but a wall-clock observer sees it).
-    Feeds the per-phase ``pred`` attributes on
-    :func:`repro.obs.trace.attribute_program_iteration` span trees and,
-    through them, trace-sourced drift attribution.
+    Feeds the per-phase ``pred`` attributes of an eager traced
+    iteration's span tree (:meth:`repro.halo.program.HaloProgram.iteration`
+    under a tracer) and, through them, trace-sourced drift attribution.
     """
     est = program.estimate
     t_pack = t_unpack = 0.0

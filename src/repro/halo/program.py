@@ -253,8 +253,9 @@ class HaloProgram:
         hosting the fused ``exchange`` (with its pack/wire/unpack
         phases, via :meth:`Communicator.neighbor_alltoallv`) and one
         ``stencil`` span per application — each phase blocked at its
-        boundary.  Jitted runs skip this entirely (the launch layer
-        attributes compiled iterations instead)."""
+        boundary.  Jitted runs skip this entirely: the launch layer
+        records a compiled iteration as one measured span, and a
+        profiler trace splits it by the ``tempi.*`` named scopes."""
         if overlap:
             mode = "monolithic" if overlap is True else str(overlap)
             return overlapped_stencil_iteration(

@@ -56,6 +56,7 @@ from repro.halo.exchange import (
     make_halo_plan,
 )
 from repro.kernels.ops import stencil_window_chain, stencil_window_update
+from repro.obs.trace import scope
 
 __all__ = [
     "StencilOp",
@@ -197,7 +198,8 @@ def stencil_apply(
     origin = tuple(hr - s for hr, s in zip(radii, shell))
     shape = tuple(n + 2 * s for n, s in zip(spec.interior, shell))
     updated = stencil_window_update(local, op.offsets, op.weight, origin, shape)
-    return jax.lax.dynamic_update_slice(local, updated, origin)
+    with scope("splice"):
+        return jax.lax.dynamic_update_slice(local, updated, origin)
 
 
 def stencil_cycle(
@@ -280,7 +282,8 @@ def stencil_interior_chain(
     makes it legal to splice the chain into the real iteration while the
     wire op is still in flight.
     """
-    x = jax.lax.dynamic_slice(local, spec.radii, spec.interior)
+    with scope("stencil"):
+        x = jax.lax.dynamic_slice(local, spec.radii, spec.interior)
     seq = list(itertools.islice(itertools.cycle(as_ops(op)), depth))
     try:
         return stencil_window_chain(
@@ -498,13 +501,16 @@ def _apply_region_split(req, spec: HaloSpec, ops: Tuple[StencilOp, ...],
         landed.add(req.wait_any().index)
         sweep()
     full = req.wait()
-    for origin, win in patches:
-        full = jax.lax.dynamic_update_slice(full, win, origin)
-    if chain_core is not None:
-        core_origin = tuple(
-            hr + r for hr, r in zip(spec.radii, first.radii)
-        )
-        full = jax.lax.dynamic_update_slice(full, chain_core, core_origin)
+    with scope("splice"):
+        for origin, win in patches:
+            full = jax.lax.dynamic_update_slice(full, win, origin)
+        if chain_core is not None:
+            core_origin = tuple(
+                hr + r for hr, r in zip(spec.radii, first.radii)
+            )
+            full = jax.lax.dynamic_update_slice(
+                full, chain_core, core_origin
+            )
     if probe is not None:
         probe["rim_regions"] = len(rims)
         probe["region_order"] = tuple(order)
@@ -604,5 +610,8 @@ def overlapped_stencil_iteration(
         valid = tuple(v - r for v, r in zip(valid, o.radii))
         if k <= depth:
             origin = tuple(hr + c for hr, c in zip(spec.radii, shrink[k - 1]))
-            full = jax.lax.dynamic_update_slice(full, chain[k - 1], origin)
+            with scope("splice"):
+                full = jax.lax.dynamic_update_slice(
+                    full, chain[k - 1], origin
+                )
     return full

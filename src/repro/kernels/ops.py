@@ -18,7 +18,12 @@ kernels share.  ``strategy`` arguments accept a Strategy object, a
 registered name, or None (the static-auto heuristic).
 
 Buffers can be any dtype/shape; they are re-viewed as bytes and then as
-W-byte words (the paper's word-size specialization) without copying.
+W-byte words (the paper's word-size specialization).  Under XLA a
+re-view is free only where the layout allows; on the TPU a whole-buffer
+byte view is a relayout copy.  Each phase traces under its
+``repro.obs.scope``: ``tempi.pack``, ``tempi.unpack``, ``tempi.stencil``,
+and ``tempi.bytes`` for every byte/word re-view, so a profiler trace
+splits their device time.
 """
 
 from __future__ import annotations
@@ -32,6 +37,7 @@ from repro.core.commit import CommittedType, KernelKind
 from repro.core.strided_block import StridedBlock
 from repro.kernels import ref as refk
 from repro.kernels.geometry import PackGeometry, plan_geometry
+from repro.obs.trace import scope
 
 __all__ = [
     "byte_view",
@@ -115,6 +121,7 @@ def shifted_window_sum(arr, offsets, origin, shape):
     return acc
 
 
+@scope("stencil")
 def stencil_window_update(arr, offsets, weight, origin, shape):
     """One weighted-neighborhood stencil update of the window
     ``arr[origin : origin + shape]``:
@@ -132,6 +139,7 @@ def stencil_window_update(arr, offsets, weight, origin, shape):
     return (1 - w) * center + (w / len(offsets)) * acc
 
 
+@scope("stencil")
 def stencil_window_chain(arr, stages):
     """Apply a *sequence* of stencil window updates, each stage consuming
     the previous stage's window: stage ``(offsets, weight, radii)``
@@ -160,9 +168,11 @@ def stencil_window_chain(arr, stages):
 
 
 # ---------------------------------------------------------------------------
-# byte / word re-viewing (no data movement under XLA)
+# byte / word re-viewing (a relayout copy on the TPU where the tiled
+# layouts differ)
 # ---------------------------------------------------------------------------
 
+@scope("bytes")
 def byte_view(arr: jax.Array) -> jax.Array:
     """Flat uint8 view of any (non-bool) array's underlying bytes."""
     if arr.dtype == jnp.bool_:
@@ -173,6 +183,7 @@ def byte_view(arr: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1)
 
 
+@scope("bytes")
 def unbyte_view(b: jax.Array, dtype, shape) -> jax.Array:
     """Inverse of :func:`byte_view`."""
     if dtype == jnp.uint8:
@@ -181,6 +192,7 @@ def unbyte_view(b: jax.Array, dtype, shape) -> jax.Array:
     return jax.lax.bitcast_convert_type(b.reshape(-1, w), dtype).reshape(shape)
 
 
+@scope("bytes")
 def as_words(b: jax.Array, w: int) -> jax.Array:
     """uint8[n] -> uintW[n/w] (n already padded to a multiple of w)."""
     if w == 1:
@@ -188,6 +200,7 @@ def as_words(b: jax.Array, w: int) -> jax.Array:
     return jax.lax.bitcast_convert_type(b.reshape(-1, w), _UINT[w])
 
 
+@scope("bytes")
 def words_to_bytes(x: jax.Array) -> jax.Array:
     w = x.dtype.itemsize
     if w == 1:
@@ -252,6 +265,7 @@ def _plan(ct: CommittedType, incount: int) -> _Plan:
 # shared word-view plumbing for the Pallas strategy kernels
 # ---------------------------------------------------------------------------
 
+@scope("bytes")
 def _prep_words(b: jax.Array, geom: PackGeometry) -> jax.Array:
     """bytes -> padded (rows_padded, pitch) word view."""
     w = geom.word_bytes
@@ -269,6 +283,7 @@ def _prep_words(b: jax.Array, geom: PackGeometry) -> jax.Array:
 _BYTES_CHUNK_WORDS = 4096
 
 
+@scope("bytes")
 def packed_bytes(packed: jax.Array) -> jax.Array:
     """Flat bytes of a (planes, rows, lanes) packed word array, converted
     ``_BYTES_CHUNK_WORDS`` words per loop step.  Turning narrow word rows
@@ -300,6 +315,7 @@ def packed_bytes(packed: jax.Array) -> jax.Array:
     return out
 
 
+@scope("bytes")
 def packed_words(packed: jax.Array, w: int, shape) -> jax.Array:
     """Inverse of :func:`packed_bytes`: flat packed bytes as a word array
     of ``shape`` (..., lanes), in the same bounded loop steps.  The
@@ -374,6 +390,7 @@ def _pack_one(
     return strat.pack_leaf(b, sb, geom, interpret)
 
 
+@scope("bytes")
 def _plane_words(buf: jax.Array, plan: _Plan, incount: int):
     """``buf`` itself as the (planes, view_rows, pitch) word view of a 3D
     plane-block geometry, when its own shape already is that view.  The
@@ -390,6 +407,7 @@ def _plane_words(buf: jax.Array, plan: _Plan, incount: int):
     return jax.lax.bitcast_convert_type(buf, _UINT[g.word_bytes])
 
 
+@scope("pack")
 def pack(
     buf: jax.Array,
     ct: CommittedType,
@@ -420,6 +438,7 @@ def pack(
     return jnp.concatenate(parts)
 
 
+@scope("pack")
 def pack_block(
     buf: jax.Array,
     sb: StridedBlock,
@@ -456,6 +475,7 @@ def _unpack_one(
     return strat.unpack_leaf(b, packed, sb, geom, interpret)
 
 
+@scope("unpack")
 def unpack(
     buf: jax.Array,
     packed: jax.Array,

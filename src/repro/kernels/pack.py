@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.geometry import PackGeometry
+from repro.obs.trace import scope
 
 __all__ = [
     "pack_rows",
@@ -52,6 +53,7 @@ __all__ = [
 # ragged wire assembly
 # ---------------------------------------------------------------------------
 
+@scope("wire")
 def pack_ragged(buf: jax.Array, leaves, total: int) -> jax.Array:
     """Scatter packed leaves directly into a flat wire buffer.
 
@@ -70,6 +72,7 @@ def pack_ragged(buf: jax.Array, leaves, total: int) -> jax.Array:
     return wire
 
 
+@scope("wire")
 def pack_compress_ragged(buf: jax.Array, leaves, total: int) -> jax.Array:
     """Fused pack+compress wire assembly.
 
@@ -86,7 +89,8 @@ def pack_compress_ragged(buf: jax.Array, leaves, total: int) -> jax.Array:
     for offset, pack_fn, encode_fn in leaves:
         part = pack_fn(buf)
         if encode_fn is not None:
-            part = encode_fn(part)
+            with scope("pack"):
+                part = encode_fn(part)
         wire = jax.lax.dynamic_update_slice(wire, part, (offset,))
     return wire
 
@@ -139,6 +143,7 @@ def pack_rows(src2d: jax.Array, geom: PackGeometry, interpret: bool = False):
                                    lambda p: (p, 0, 0)),
             out_shape=out_shape,
             interpret=interpret,
+            name="tempi_pack_planes",
         )(plane_view(src2d, geom))
 
     g = geom.group
@@ -153,6 +158,7 @@ def pack_rows(src2d: jax.Array, geom: PackGeometry, interpret: bool = False):
         out_specs=pl.BlockSpec((1, g, geom.lanes), lambda p, i: (p, i, 0)),
         out_shape=out_shape,
         interpret=interpret,
+        name="tempi_pack_rows",
     )(src2d)
 
 
@@ -225,4 +231,5 @@ def pack_dma(
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
+        name="tempi_pack_dma",
     )(src2d)

@@ -30,10 +30,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.geometry import PackGeometry
 from repro.kernels.pack import dma_steps, plane_view
+from repro.obs.trace import scope
 
 __all__ = ["unpack_rows", "unpack_dma", "unpack_ragged", "decode_unpack_ragged"]
 
 
+@scope("unpack")
 def unpack_ragged(dst: jax.Array, wire: jax.Array, leaves) -> jax.Array:
     """Inverse of :func:`repro.kernels.pack.pack_ragged`: slice each
     leaf's exact wire segment out of the flat received buffer and
@@ -52,6 +54,7 @@ def unpack_ragged(dst: jax.Array, wire: jax.Array, leaves) -> jax.Array:
     return dst
 
 
+@scope("unpack")
 def decode_unpack_ragged(dst: jax.Array, wire: jax.Array, leaves) -> jax.Array:
     """Fused decompress+unpack: inverse of
     :func:`repro.kernels.pack.pack_compress_ragged`.
@@ -120,6 +123,7 @@ def unpack_rows(
             out_shape=jax.ShapeDtypeStruct(dst3d.shape, dst3d.dtype),
             input_output_aliases={0: 0},
             interpret=interpret,
+            name="tempi_unpack_planes",
         )(dst3d, packed3d)
         return out.reshape(dst2d.shape)
 
@@ -139,6 +143,7 @@ def unpack_rows(
         out_shape=jax.ShapeDtypeStruct(dst2d.shape, dst2d.dtype),
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="tempi_unpack_rows",
     )(dst2d, packed3d)
 
 
@@ -183,4 +188,5 @@ def unpack_dma(
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
+        name="tempi_unpack_dma",
     )(packed3d, dst2d)

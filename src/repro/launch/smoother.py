@@ -177,50 +177,38 @@ def run_smoother(
         # Communicator's eager probes never fire — time the compiled
         # step here instead.  AOT-compile first so compile time never
         # pollutes the samples, and block each iteration (async dispatch
-        # would under-report).  The tracer gets the same observation as
-        # an attributed span tree: the measured iteration wall time
-        # split across phases in the model's predicted proportions.
+        # would under-report).  The tracer records each iteration as one
+        # measured span; its phases are fused, and a profiler trace
+        # splits them by the program's named scopes.
         import time
+        from contextlib import nullcontext
 
-        from repro.fleet.telemetry import predict_program_phases
+        from repro.fleet.telemetry import predict_program_iteration
 
-        phases = predict_program_phases(program, comm.model)
-        predicted = sum(phases.values())
+        predicted = predict_program_iteration(program, comm.model)
         if telemetry is not None:
             telemetry.register(
                 program.fingerprint, predicted, f"program/s={program.steps}"
             )
-        # overlap runs care about per-direction completion: attribute
-        # the wire span across the delta classes in the model's
-        # predicted completion profile so a slow link is visible per
-        # class, not just per exchange
-        class_pred: Tuple[float, ...] = ()
-        if overlap != "off" and tracer is not None:
-            try:
-                class_pred = tuple(
-                    comm.model.price_class_completions(program.plan.wire)
-                )
-            except Exception:
-                class_pred = ()
         try:
             run = step.lower(x).compile()
         except AttributeError:  # not a jit-wrapped callable
             run = step
         jax.block_until_ready(x)
         for i in range(iters):
-            t0 = time.perf_counter()
-            x = run(x)
-            jax.block_until_ready(x)
-            dt = time.perf_counter() - t0
+            span = nullcontext() if tracer is None else tracer.span(
+                "program_iteration", fingerprint=program.fingerprint,
+                strategy=f"program/s={program.steps}", steps=program.steps,
+                cycle_len=program.cycle_len, pinned=bool(program.pinned),
+                pred=predicted, iteration=i,
+            )
+            with span:
+                t0 = time.perf_counter()
+                x = run(x)
+                jax.block_until_ready(x)
+                dt = time.perf_counter() - t0
             if telemetry is not None:
                 telemetry.observe(program.fingerprint, dt)
-            if tracer is not None:
-                from repro.obs.trace import attribute_program_iteration
-
-                attribute_program_iteration(
-                    tracer, program, t0, dt, phases, iteration=i,
-                    class_pred=class_pred,
-                )
     out = np.asarray(x).reshape(R, az, ay, ax)
     checksum = float(
         out[:, rz:rz + nz, ry:ry + ny, rx:rx + nx].sum()

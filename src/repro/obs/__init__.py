@@ -6,7 +6,9 @@ model prices):
 * :mod:`repro.obs.trace` — hierarchical spans (``program_iteration`` →
   ``exchange`` → ``plan``/``pack``/``wire``/``unpack`` → ``stencil``)
   with decision signatures and predicted-seconds attributes,
-  tracer-guarded like the telemetry probe;
+  tracer-guarded like the telemetry probe, on the profiler's clock; and
+  :func:`scope`, the ``tempi.<phase>`` named scopes that split a
+  compiled step's device time by phase in a profiler trace;
 * :mod:`repro.obs.metrics` — process-local counters/gauges
   (:meth:`Communicator.stats` publishes; ``save()`` persists);
 * :mod:`repro.obs.export` — Chrome-trace JSON (Perfetto /
@@ -35,10 +37,11 @@ from repro.obs.metrics import (
 from repro.obs.trace import (
     DEFAULT_MAX_SPANS,
     PHASES,
+    SCOPE_PREFIX,
     TRACE_FORMAT,
     Span,
     Tracer,
-    attribute_program_iteration,
+    scope,
 )
 
 __all__ = [
@@ -46,8 +49,9 @@ __all__ = [
     "PHASES",
     "DEFAULT_MAX_SPANS",
     "Span",
+    "SCOPE_PREFIX",
     "Tracer",
-    "attribute_program_iteration",
+    "scope",
     "METRICS_FORMAT",
     "METRICS_FILENAME",
     "MetricsRegistry",

@@ -16,7 +16,7 @@ per phase, per exchange, without the model in hand.
   + ``strategy``);
 * every ``wire_class`` span (per-delta-class completion, region-split
   overlap) identifies its class: a ``class`` index plus the wire-plan
-  key (``fingerprint`` on eager drains, ``key`` on attributed ones);
+  ``fingerprint``;
 * communication avoidance holds: a ``program_iteration`` span with
   fusion depth ``s`` contains at most ONE exchange and at least
   ``s`` stencil applications — exchanges per application <= 1/s.
@@ -140,14 +140,12 @@ def aggregate_spans(
     spans: Sequence[Span],
 ) -> Dict[str, Dict[str, Dict[str, float]]]:
     """Per-decision-fingerprint phase sums:
-    ``{fingerprint: {phase: {count, observed, predicted, attributed}}}``.
+    ``{fingerprint: {phase: {count, observed, predicted}}}``.
 
     Each pack/wire/unpack/stencil span is credited to the nearest
     enclosing span carrying a ``fingerprint`` attribute (the decision
     key), summing observed wall seconds and the predicted seconds the
-    span recorded (``pred``).  ``attributed`` counts the spans whose
-    timing was model-proportioned rather than directly measured, so a
-    consumer can tell a real per-phase observation from a scaled one.
+    span recorded (``pred``).
     """
     by_id = {s.span_id: s for s in spans}
     out: Dict[str, Dict[str, Dict[str, float]]] = {}
@@ -163,14 +161,11 @@ def aggregate_spans(
         fp = str(p.attrs["fingerprint"])
         rec = out.setdefault(fp, {}).setdefault(
             s.name,
-            {"count": 0, "observed": 0.0, "predicted": 0.0,
-             "attributed": 0},
+            {"count": 0, "observed": 0.0, "predicted": 0.0},
         )
         rec["count"] += 1
         rec["observed"] += s.duration
         rec["predicted"] += float(s.attrs.get("pred", 0.0) or 0.0)
-        if s.attrs.get("attributed"):
-            rec["attributed"] += 1
     return out
 
 
@@ -210,13 +205,12 @@ def _render_group(lines: List[str], group: List[Span],
                f" {head.attrs.get('strategy', '')}")
         if "schedule" in head.attrs:
             sig += f" {head.attrs['schedule']}/{head.attrs.get('wire_bytes', '?')}B"
-    attributed = any(s.attrs.get("attributed") for s in group)
     ratio = f"{obs / pred:8.3f}" if pred > 0 else f"{'-':>8s}"
     lines.append(
         f"{'  ' * indent}{head.name:<{max(24 - 2 * indent, 8)}s}"
         f" n={n:<5d} obs={obs / n * 1e6:10.1f}us"
         f" pred={pred / n * 1e6:10.1f}us obs/pred={ratio}"
-        f"{' [attributed]' if attributed else ''}{sig}"
+        f"{sig}"
     )
     # recurse: pool the whole sibling group's children, regroup by name
     sub: Dict[Tuple[str, str], List[Span]] = {}
@@ -301,10 +295,10 @@ def validate(trace: dict) -> List[str]:
                 errors.append(
                     f"wire_class span {s.span_id}: no class index"
                 )
-            if not (s.attrs.get("fingerprint") or s.attrs.get("key")):
+            if not s.attrs.get("fingerprint"):
                 errors.append(
-                    f"wire_class span {s.span_id}: no wire-plan key "
-                    "(fingerprint/key missing)"
+                    f"wire_class span {s.span_id}: no wire-plan "
+                    "fingerprint"
                 )
         if s.name == "program_iteration":
             steps = int(s.attrs.get("steps", 1) or 1)

@@ -8,7 +8,8 @@ the model prices *separately*, and Hunold et al. show the terms drift
 independently.  This module records that decomposition as it happens:
 
 * :class:`Span` — one timed region with free-form attributes.  The
-  hierarchy mirrors the execution structure::
+  hierarchy mirrors the execution structure of an eager iteration (a
+  compiled one is a single ``program_iteration`` span)::
 
       program_iteration            (one deep-halo iteration)
         exchange                   (the fused collective, decision-keyed)
@@ -31,11 +32,21 @@ independently.  This module records that decomposition as it happens:
   :meth:`Tracer.span` records nothing unless
   ``jax.core.trace_ctx.is_top_level()`` says execution is eager (callers
   additionally skip on tracer *operands*, same as telemetry).  Eager
-  paths time phases with ``block_until_ready`` at each span exit;
-  compiled (fused) iterations are recorded after the fact by
-  :func:`attribute_program_iteration`, which splits the observed AOT
-  iteration time across phases in the model's predicted proportions and
-  marks the children ``attributed=True``.
+  paths time phases with ``block_until_ready`` at each span exit, and
+  each recorded span also opens a ``jax.profiler.TraceAnnotation``
+  named ``tempi.<name>``, so it lands in a profiler trace on the same
+  clock as the device's operations.  A compiled iteration is one
+  measured ``program_iteration`` span: its phases are fused, and their
+  split comes from the device trace instead.
+
+* :func:`scope` — ``jax.named_scope("tempi.<name>")``.  The program
+  wraps each phase in one at trace time (``pack``, ``unpack``,
+  ``bytes``, ``wire``, ``stencil``, ``splice``), which only adds HLO
+  metadata: every instruction of the compiled program carries its
+  innermost ``tempi.`` scope in its ``op_name``, so a profiler trace
+  joined with the optimized HLO splits a compiled step's time into
+  pack / unpack / bytes / wire / stencil / splice, with no run-time
+  cost or switch.
 
 Export to Chrome-trace JSON / text flamecharts lives in
 :mod:`repro.obs.export`; ``python -m repro.obs`` is the CLI.
@@ -46,7 +57,7 @@ from __future__ import annotations
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, Iterator, List, Optional, Sequence
+from typing import Dict, Iterator, List, Optional
 
 import jax
 
@@ -54,9 +65,10 @@ __all__ = [
     "TRACE_FORMAT",
     "PHASES",
     "DEFAULT_MAX_SPANS",
+    "SCOPE_PREFIX",
     "Span",
     "Tracer",
-    "attribute_program_iteration",
+    "scope",
 ]
 
 #: bump when the exported span schema changes incompatibly
@@ -66,9 +78,21 @@ TRACE_FORMAT = 1
 #: the execution order inside an exchange)
 PHASES = ("pack", "wire", "unpack", "stencil")
 
+#: prefix of the program's named scopes and of its host spans in a
+#: profiler trace
+SCOPE_PREFIX = "tempi."
+
 #: span-count cap — a million-iteration job must not grow an unbounded
 #: trace; past the cap spans are dropped and counted, never an error
 DEFAULT_MAX_SPANS = 200_000
+
+
+def scope(name: str):
+    """``jax.named_scope("tempi.<name>")``: a context manager, or a
+    decorator, that credits the device operations traced inside it to
+    the phase ``name``.  It only adds metadata to
+    the HLO, so it costs nothing at run time."""
+    return jax.named_scope(SCOPE_PREFIX + name)
 
 
 def _trace_state_clean() -> bool:
@@ -146,7 +170,9 @@ class Tracer:
         """Record a timed region.  Yields the :class:`Span` (mutate
         ``.attrs`` freely before exit) — or ``None`` when guarded off
         (inside a jax trace, disabled, or at the span cap), in which
-        case nothing is recorded and the body runs untouched.
+        case nothing is recorded and the body runs untouched.  A
+        recorded span is also a ``tempi.<name>`` host span of any
+        profiler trace that is running.
 
         The caller owns synchronization: block (``block_until_ready``)
         before exit or the span under-reports async dispatch.
@@ -161,15 +187,17 @@ class Tracer:
             return
         self._stack.append(sp.span_id)
         try:
-            yield sp
+            with jax.profiler.TraceAnnotation(SCOPE_PREFIX + name):
+                yield sp
         finally:
             sp.duration = time.perf_counter() - sp.start
             self._stack.pop()
 
     def add_manual(self, name: str, start: float, duration: float,
                    parent: Optional[Span] = None, **attrs) -> Optional[Span]:
-        """Record a span with explicit timing (compiled-iteration
-        attribution, host-side planning timed outside a ``with``).
+        """Record a span with explicit timing (a compiled iteration timed
+        around its blocking call, host-side planning timed outside a
+        ``with``).
         Nests under ``parent`` when given, else under the innermost open
         :meth:`span`, else at the root."""
         if not self.enabled:
@@ -191,92 +219,3 @@ class Tracer:
         from repro.obs.export import aggregate_spans
 
         return aggregate_spans(self._spans)
-
-
-def attribute_program_iteration(
-    tracer: Tracer,
-    program,
-    t0: float,
-    seconds: float,
-    phases: Dict[str, float],
-    iteration: Optional[int] = None,
-    class_pred: Sequence[float] = (),
-) -> Optional[Span]:
-    """Record one *compiled* deep-halo iteration as an attributed span
-    tree.
-
-    Inside ``jit`` the phases are fused — only the whole-iteration wall
-    time (``seconds``, timed by the launch layer around the AOT-compiled
-    step) is observable.  This splits it across the pack/wire/unpack/
-    stencil children in the proportions of the model's per-phase
-    predictions (``phases``, from
-    :func:`repro.fleet.telemetry.predict_program_phases`), marking every
-    span ``attributed=True`` so consumers know the split is model-shaped
-    while the totals are measured.  The ``exchange`` child carries the
-    program's full decision signature.
-
-    ``class_pred`` (the model's per-delta-class completion times, from
-    :meth:`~repro.comm.perfmodel.PerfModel.price_class_completions`)
-    additionally attributes the wire span across its delta classes:
-    one ``wire_class`` child per class, each spanning wire-start to its
-    predicted completion fraction of the wire span — the per-direction
-    view drift attribution uses to see which link is slow when the
-    iteration runs region-split overlap.
-    """
-    total = sum(phases.values())
-    if total <= 0.0 or not tracer.enabled:
-        return None
-    # this runs once per compiled iteration on the launch hot loop —
-    # gated at <2% of an iteration by `bench_measure --assert-trace-
-    # overhead` — so the fingerprint (a content hash) is computed once
-    # and spans are allocated directly, skipping add_manual's kwargs
-    scale = seconds / total
-    fingerprint = program.fingerprint
-    steps = program.steps
-    strategy = f"program/s={steps}"
-    attrs: Dict[str, object] = {
-        "fingerprint": fingerprint, "strategy": strategy,
-        "steps": steps, "cycle_len": program.cycle_len,
-        "pinned": bool(program.pinned), "attributed": True, "pred": total,
-    }
-    if iteration is not None:
-        attrs["iteration"] = int(iteration)
-    alloc = tracer._alloc
-    it = alloc("program_iteration", t0, seconds, None, attrs)
-    if it is None:
-        return None
-    wire = program.plan.wire
-    pred_ex = phases.get("pack", 0.0) + phases.get("wire", 0.0) \
-        + phases.get("unpack", 0.0)
-    ex = alloc(
-        "exchange", t0, pred_ex * scale, it.span_id,
-        {"fingerprint": fingerprint, "strategy": strategy,
-         "schedule": wire.schedule, "wire_bytes": int(wire.issued_bytes),
-         "attributed": True, "pred": pred_ex},
-    )
-    ex_id = ex.span_id if ex is not None else it.span_id
-    cursor = t0
-    for ph in ("pack", "wire", "unpack"):
-        p = phases.get(ph, 0.0)
-        d = p * scale
-        sp = alloc(ph, cursor, d, ex_id, {"pred": p, "attributed": True})
-        if ph == "wire" and sp is not None and class_pred:
-            # per-delta-class completion profile: each class's span runs
-            # wire-start -> its predicted completion fraction
-            last = max(class_pred) or 1.0
-            for g, tc in enumerate(class_pred):
-                alloc("wire_class", cursor, d * (float(tc) / last),
-                      sp.span_id,
-                      {"pred": float(tc), "attributed": True,
-                       "class": g,
-                       "key": f"{wire.fingerprint}/c{g}"})
-        cursor += d
-    napp = max(program.applications, 1)
-    pred_st = phases.get("stencil", 0.0)
-    per = pred_st * scale / napp
-    for a in range(napp):
-        alloc("stencil", cursor, per, it.span_id,
-              {"pred": pred_st / napp, "attributed": True,
-               "application": a})
-        cursor += per
-    return it

@@ -1,6 +1,9 @@
 """Observability layer: hierarchical spans, the jit tracer guard,
-Chrome-trace export/validation, the metrics registry, and trace-sourced
-drift attribution (PR 7)."""
+Chrome-trace export/validation, the metrics registry, trace-sourced
+drift attribution, and the ``tempi.*`` named scopes and profiler host
+spans that put the program's phases in a device trace."""
+
+import re
 
 import json
 
@@ -17,7 +20,6 @@ from repro.obs import (
     Tracer,
     aggregate_events,
     aggregate_spans,
-    attribute_program_iteration,
     default_metrics,
     load_chrome_trace,
     publish_comm_stats,
@@ -216,59 +218,13 @@ def test_communicator_neighbor_alltoallv_span_hierarchy(monkeypatch):
 
 
 # ===========================================================================
-# attributed program iterations
+# compiled program iterations: one measured span each
 # ===========================================================================
 
-def _program(comm):
-    from repro.halo.program import build_halo_program
-
-    return build_halo_program((1, 1, 1), (8, 8, 8), comm, steps=2)
-
-
-class TestAttributeProgramIteration:
-    def test_span_tree_shape_and_scaling(self):
-        from repro.comm.api import Communicator
-        from repro.fleet import predict_program_phases
-
-        comm = Communicator(axis_name="data", decisions=DecisionCache())
-        program = _program(comm)
-        phases = predict_program_phases(program, comm.model)
-        tr = Tracer()
-        it = attribute_program_iteration(
-            tr, program, t0=10.0, seconds=2e-3, phases=phases, iteration=7
-        )
-        assert it.duration == pytest.approx(2e-3)
-        assert it.attrs["iteration"] == 7
-        assert it.attrs["strategy"] == f"program/s={program.steps}"
-        assert it.attrs["attributed"] is True
-        ex = [s for s in tr.spans if s.name == "exchange"]
-        assert len(ex) == 1 and ex[0].parent_id == it.span_id
-        assert ex[0].attrs["fingerprint"] == program.fingerprint
-        st = [s for s in tr.spans if s.name == "stencil"]
-        assert len(st) == program.applications
-        # the children partition the observed iteration exactly
-        leaf = [s for s in tr.spans if s.name in
-                ("pack", "wire", "unpack", "stencil")]
-        assert sum(s.duration for s in leaf) == pytest.approx(2e-3)
-        # ...in the model's predicted proportions
-        pk = [s for s in tr.spans if s.name == "pack"][0]
-        total = sum(phases.values())
-        assert pk.duration == pytest.approx(
-            2e-3 * phases["pack"] / total
-        )
-
-    def test_zero_prediction_records_nothing(self):
-        tr = Tracer()
-        assert attribute_program_iteration(
-            tr, object(), 0.0, 1e-3, {"pack": 0.0}
-        ) is None
-        assert len(tr) == 0
-
-
-def test_run_smoother_traced_exchanges_bounded_by_iterations():
-    # the launch loop records one attributed iteration tree per compiled
-    # iteration: exchanges <= iterations is the communication-avoidance
-    # invariant the CI trace check gates on
+@pytest.fixture(scope="module")
+def smoother_spans():
+    """The tracer of a three-iteration compiled smoother run, and the
+    run's report."""
     from repro.comm.api import Communicator
     from repro.launch.smoother import run_smoother
 
@@ -278,13 +234,46 @@ def test_run_smoother_traced_exchanges_bounded_by_iterations():
     )
     report = run_smoother(comm, iters=3, interior=(8, 8, 8),
                           cycle="smooth", halo_steps=2)
+    return tr, report
+
+
+class TestCompiledIterationSpans:
+    def test_one_measured_span_per_iteration(self, smoother_spans):
+        tr, report = smoother_spans
+        iters = [s for s in tr.spans if s.name == "program_iteration"]
+        assert [s.attrs["iteration"] for s in iters] == [0, 1, 2]
+        assert all(s.duration > 0 for s in iters)
+        assert all(s.attrs["fingerprint"] == report.program.fingerprint
+                   for s in iters)
+        assert all(s.attrs["strategy"] == f"program/s={report.program.steps}"
+                   and s.attrs["pred"] > 0 for s in iters)
+
+    def test_no_invented_children(self, smoother_spans):
+        # the phases of a compiled iteration are fused: the tracer
+        # records none of them, so the drift feed gets no phase evidence
+        # from it and no span is model-proportioned
+        tr, _ = smoother_spans
+        iters = {s.span_id for s in tr.spans if s.name == "program_iteration"}
+        assert len(iters) == 3
+        assert not [s for s in tr.spans if s.parent_id in iters]
+        assert not {s.name for s in tr.spans} & {"pack", "wire", "unpack",
+                                                 "stencil"}
+        assert tr.phase_aggregates() == {}
+        assert not any("attributed" in s.attrs for s in tr.spans)
+
+
+def test_run_smoother_traced_exchanges_bounded_by_iterations(smoother_spans):
+    # the launch loop records one measured span per compiled iteration
+    # and no exchange span of its own: exchanges <= iterations is the
+    # communication-avoidance invariant the CI trace check gates on
+    tr, report = smoother_spans
     iters = [s for s in tr.spans if s.name == "program_iteration"]
     ex = [s for s in tr.spans if s.name == "exchange"]
     assert len(iters) == 3
     assert len(ex) <= len(iters)
     assert all(s.attrs["fingerprint"] == report.program.fingerprint
-               for s in ex)
-    assert all(s.attrs.get("attributed") for s in iters)
+               for s in iters)
+    assert validate(to_chrome_trace(tr)) == []
 
 
 # ===========================================================================
@@ -481,7 +470,7 @@ def _trace_agg(obs_scale: float, count: int = 4, key: str = "prog1") -> dict:
     # phase aggregates as Tracer.phase_aggregates() shapes them
     return {key: {
         ph: {"count": count, "observed": obs_scale * pred,
-             "predicted": pred, "attributed": 0}
+             "predicted": pred}
         for ph, pred in
         (("pack", 1e-5), ("wire", 2e-5), ("unpack", 1e-5),
          ("stencil", 4e-5))
@@ -571,20 +560,31 @@ class TestTraceDrift:
         prog = [f for f in back.findings if f.fingerprint == "prog1"][0]
         assert prog.phase_ratios["wire"] == pytest.approx(10.0)
 
-    def test_tracer_aggregates_feed_audit_end_to_end(self):
-        # Tracer -> phase_aggregates -> audit: the wiring the smoother's
-        # --trace/--drift-report path uses
-        from repro.comm.api import Communicator
-        from repro.fleet import DriftDetector, predict_program_phases
-        from repro.launch.smoother import run_smoother
+    def test_tracer_aggregates_feed_audit_end_to_end(self, monkeypatch):
+        # Tracer -> phase_aggregates -> audit, on the spans an eager
+        # iteration measures phase by phase (a compiled iteration is one
+        # span, with no phase evidence).  The wire ops are stubbed to a
+        # self-send: one rank, no eager collective on the CPU.
+        from repro.comm import api
+        from repro.fleet import DriftDetector
+        from repro.halo.program import build_halo_program
+        from repro.launch.smoother import initial_state, smoother_cycle
 
+        monkeypatch.setattr(api.lax, "ppermute", lambda x, axis, perm: x)
+        monkeypatch.setattr(api.lax, "all_to_all", lambda x, axis, **kw: x)
+        monkeypatch.setattr(api.lax, "axis_index", lambda axis: 0)
         tr = Tracer()
         decisions = DecisionCache()
-        comm = Communicator(
+        comm = api.Communicator(
             axis_name="data", decisions=decisions, tracer=tr
         )
-        run_smoother(comm, iters=4, interior=(8, 8, 8), cycle="smooth",
-                     halo_steps="auto")
+        program = build_halo_program(
+            (1, 1, 1), (8, 8, 8), comm, ops=smoother_cycle("smooth"),
+            steps="auto", schedule_policy="exact",
+        )
+        x = jnp.asarray(initial_state(program, 0))
+        for _ in range(2):
+            x = program.iteration(x, comm, "data")
         rep = DriftDetector(min_samples=2).audit(
             decisions, comm.model.params, trace=tr.phase_aggregates()
         )
@@ -593,7 +593,7 @@ class TestTraceDrift:
         assert len(prog) == 1
         assert prog[0].source == "trace"
         assert prog[0].phase_ratios  # direct per-term evidence on file
-        assert prog[0].samples >= 4
+        assert prog[0].samples >= 2
 
 
 # ===========================================================================
@@ -614,3 +614,135 @@ def test_fleet_stats_cli_renders_persisted_metrics(tmp_path, capsys):
     assert '"gauges"' in out
     # empty store: still exits 0 with an empty table
     assert main(["stats", "--store", str(tmp_path / "empty")]) == 0
+
+
+# ===========================================================================
+# named scopes in the compiled program, host spans on the profiler clock
+# ===========================================================================
+
+def _scope_resolver(mlir: str):
+    """A function from an op line of a module lowered with debug info to
+    the innermost ``tempi.`` scope of the op's location ('' if none)."""
+    defs = dict(re.findall(r"^(#loc\d+) = (loc\(.*\))$", mlir, re.M))
+
+    def names(loc: str, depth: int = 0):
+        out = re.findall(r'"([^"]*)"', loc)
+        for ref in re.findall(r"#loc\d+", loc):
+            if depth < 32 and ref in defs:
+                out += names(defs[ref], depth + 1)
+        return out
+
+    def scope_of(line: str) -> str:
+        m = re.search(r"loc\((#loc\d+|\".*\")\)\s*$", line)
+        paths = [n for n in names(m.group(1)) if "tempi." in n] if m else []
+        found = re.findall(r"tempi\.[a-z]+", paths[0]) if paths else []
+        return found[-1] if found else ""
+
+    return scope_of
+
+
+@pytest.fixture(scope="module")
+def lowered_steps():
+    """The program step for a small one-rank grid, lowered on the default
+    path and with region-split overlap (debug info on)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.comm.api import Communicator
+    from repro.halo import build_halo_program, make_program_step
+
+    comm = Communicator(axis_name="ranks", decisions=DecisionCache())
+    program = build_halo_program((1, 1, 1), (8, 8, 8), comm, steps=2)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("ranks",))
+    x = jax.ShapeDtypeStruct(program.spec.alloc, jnp.float32)
+    return program, {
+        overlap: make_program_step(program, comm, mesh, overlap=overlap)
+        .lower(x).as_text(debug_info=True)
+        for overlap in (False, "region")
+    }
+
+
+def _scopes_found(text: str) -> set:
+    scope_of = _scope_resolver(text)
+    return {scope_of(line) for line in text.splitlines()
+            if "stablehlo." in line}
+
+
+@pytest.fixture(scope="module")
+def step_scopes(lowered_steps):
+    _, text = lowered_steps
+    return {overlap: _scopes_found(t) for overlap, t in text.items()}
+
+
+@pytest.mark.parametrize("overlap", [False, "region"])
+@pytest.mark.parametrize("name", ["pack", "unpack", "wire", "bytes",
+                                  "stencil", "splice"])
+def test_program_step_carries_scope(step_scopes, name, overlap):
+    assert f"tempi.{name}" in step_scopes[overlap]
+
+
+@pytest.mark.parametrize("overlap", [False, "region"])
+def test_stencil_write_backs_are_splices(lowered_steps, overlap):
+    # every write-back of an updated window into the fp32 field is a
+    # whole-field splice, credited to tempi.splice and nothing else
+    program, text = lowered_steps
+    az, ay, ax = program.spec.alloc
+    field = f"-> tensor<{az}x{ay}x{ax}xf32>"
+    scope_of = _scope_resolver(text[overlap])
+    dus = [line for line in text[overlap].splitlines()
+           if "stablehlo.dynamic_update_slice" in line and field in line]
+    assert dus
+    assert {scope_of(line) for line in dus} == {"tempi.splice"}
+
+
+@pytest.mark.parametrize("strategy", ["rows", "dma", "xla"])
+@pytest.mark.parametrize("phase", ["pack", "unpack"])
+def test_comm_pack_and_unpack_carry_scopes(strategy, phase):
+    from repro.comm import Communicator, FixedPolicy
+    from repro.core import BYTE, Vector
+
+    comm = Communicator(policy=FixedPolicy(strategy))
+    ct = comm.commit(Vector(64, 8, 512, BYTE))
+    # a word buffer: its byte view is a relayout for every strategy
+    buf = jax.ShapeDtypeStruct((ct.extent // 4,), jnp.float32)
+    packed = jax.ShapeDtypeStruct((ct.size,), jnp.uint8)
+    if phase == "pack":
+        low = jax.jit(lambda b: comm.pack(b, ct)).lower(buf)
+    else:
+        low = jax.jit(lambda b, p: comm.unpack(b, p, ct)).lower(buf, packed)
+    found = _scopes_found(low.as_text(debug_info=True))
+    assert {f"tempi.{phase}", "tempi.bytes"} <= found
+    if phase == "pack":
+        assert "tempi.unpack" not in found
+
+
+def _host_events(trace_dir):
+    import pathlib
+
+    from jax.profiler import ProfileData
+
+    (path,) = pathlib.Path(trace_dir).rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [e.name for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_tracer_span_is_a_profiler_host_span(tmp_path, enabled):
+    # an active span is also a tempi.<name> host event of the profiler
+    # trace, on the device's clock; a guarded-off span writes none
+    tr = Tracer(enabled=enabled)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("exchange"):
+            jnp.ones(8).block_until_ready()
+
+        @jax.jit
+        def traced(x):
+            with tr.span("pack"):  # inside a jax trace: guarded off
+                return x + 1
+
+        traced(jnp.ones(8)).block_until_ready()
+    names = _host_events(tmp_path)
+    assert names.count("tempi.exchange") == (1 if enabled else 0)
+    assert "tempi.pack" not in names
+    assert len(tr) == (1 if enabled else 0)

@@ -88,6 +88,40 @@ def test_pack_unpack_kernels_compile(one_chip, label, strategy):
         assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("label, strategy, kernels", [
+    ("subarray 32B x32768", "rows", ("tempi_pack_rows", "tempi_unpack_rows")),
+    ("subarray 32B x32768", "dma", ("tempi_pack_dma", "tempi_unpack_dma")),
+    ("halo x-face 3D", "rows", ("tempi_pack_planes", "tempi_unpack_planes")),
+])
+def test_compiled_kernels_carry_their_names_and_scopes(one_chip, label,
+                                                       strategy, kernels):
+    # a trace names each Pallas kernel's custom call by its own name, and
+    # its metadata credits it to tempi.pack / tempi.unpack; the word view
+    # of the float buffer is a tempi.bytes relayout
+    import re
+
+    dt, shape, dtype = OBJECTS[label]
+    ct = COMM.commit(dt)
+    strat = COMM.strategies.get(strategy)
+    buf = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    packed = jax.ShapeDtypeStruct((ct.size,), jnp.uint8, sharding=one_chip)
+    pack = jax.jit(lambda b: strat.pack(b, ct, interpret=False))
+    unpack = jax.jit(lambda b, p: strat.unpack(b, p, ct, interpret=False))
+    for compiled, kernel, phase in (
+        (pack.lower(buf).compile(), kernels[0], "tempi.pack"),
+        (unpack.lower(buf, packed).compile(), kernels[1], "tempi.unpack"),
+    ):
+        calls = [line for line in compiled.as_text().splitlines()
+                 if "tpu_custom_call" in line and " = " in line]
+        assert calls
+        for line in calls:
+            name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+            assert name.startswith(kernel)
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert re.findall(r"tempi\.[a-z]+", op_name)[-1] == phase
+        assert "tempi.bytes" in compiled.as_text()
+
+
 def test_native_ragged_exchange_compiles_on_2x2(topo):
     assert has_ragged_all_to_all(topo.devices)
     spec = HaloSpec(grid=(4, 1, 1), interior=(16, 16, 16), radius=1)
