@@ -154,6 +154,11 @@ class Strategy:
     supports_varlen: bool = False
     #: calibration sweep cap on block count (None = unbounded)
     calibration_cap: Optional[int] = None
+    #: ``pack_leaf`` / ``unpack_leaf`` run the Pallas kernels on a
+    #: row-group geometry, so they also take a buffer flat at its own
+    #: element width under a geometry narrowed to that width (and
+    #: ``unpack_leaf`` returns it so); see ``repro.kernels.ops``
+    leaf_kernels: bool = False
 
     # -- applicability ----------------------------------------------------
     def applicable(self, ct: CommittedType) -> bool:
@@ -320,6 +325,7 @@ class Rows(Strategy):
     "device" method: Pallas double-buffers full-pitch row groups."""
 
     name = "rows"
+    leaf_kernels = True
 
     def applicable(self, ct: CommittedType) -> bool:
         return ct.block is not None and plan_geometry(ct.block) is not None
@@ -371,6 +377,7 @@ class Dma(Strategy):
     a calibrated table replaces it with what the chip measures."""
 
     name = "dma"
+    leaf_kernels = True
 
     def applicable(self, ct: CommittedType) -> bool:
         if ct.block is None:
@@ -525,6 +532,7 @@ class Auto(Strategy):
     defers to :func:`static_choice` per leaf."""
 
     name = "auto"
+    leaf_kernels = True
     selectable = False
 
     def model_pack(self, model, ct, incount):

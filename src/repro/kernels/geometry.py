@@ -4,8 +4,12 @@ This is the TPU adaptation of the paper's §3.3 kernel selection.  On CUDA
 the paper maps counts[0..2] to thread-block X/Y/Z and specializes a word
 size W.  On TPU the equivalents are:
 
-* word width W  -> re-view the byte buffer as uint{8,16,32}[.] so the
-  128-lane axis moves W bytes per lane (``repro.kernels.ops``);
+* word width W  -> view the buffer as uint{8,16,32}[.] so the 128-lane
+  axis moves W bytes per lane (``repro.kernels.ops``).  The kernels run
+  on the narrower of the type's word and the buffer's element width: on
+  the TPU a flat narrow buffer keeps each element in its own 32-bit
+  container, so viewing it as wider words is a relayout copy of the
+  whole buffer, where a same-width view is free;
 * thread grid   -> a Pallas grid over (planes, row-groups) with BlockSpec
   index maps that jump by the block stride — possible *because* the
   canonical StridedBlock has regular scalar strides (no per-block
@@ -48,7 +52,8 @@ class PackGeometry:
     of it in fast memory (``plane_block``).
     """
 
-    word_bytes: int      # W
+    word_bytes: int      # W: the kernel word (a buffer's narrower itemsize
+                         # where one is narrower, see ``word_bytes=``)
     lanes: int           # counts[0] // W — words per contiguous block
     rows: int            # counts[1]     — blocks per plane
     planes: int          # counts[2]     — plane count (1 for 2D)
@@ -106,6 +111,11 @@ def plan_geometry(
     vmem_budget: int = VMEM_BUDGET_BYTES,
 ) -> Optional[PackGeometry]:
     """Plan the aligned row-kernel geometry for a 2D/3D StridedBlock.
+
+    ``word_bytes`` defaults to the widest word (up to 4 bytes) that the
+    block's offsets and sizes allow.  ``repro.kernels.ops`` passes a
+    buffer's narrower itemsize instead, so the kernels read the buffer at
+    its own width rather than relayouting it into wider words.
 
     Returns None when the aligned path does not apply; callers fall back
     to the generic gather path.  Conditions (each checked on host

@@ -17,10 +17,18 @@ this module owns the strategy-independent machinery: plan caching, the
 kernels share.  ``strategy`` arguments accept a Strategy object, a
 registered name, or None (the static-auto heuristic).
 
-Buffers can be any dtype/shape; they are re-viewed as bytes and then as
-W-byte words (the paper's word-size specialization).  Under XLA a
-re-view is free only where the layout allows; on the TPU a whole-buffer
-byte view is a relayout copy.  Each phase traces under its
+Buffers can be any dtype/shape.  The strategy kernels run on a K-byte
+kernel word, the narrower of the geometry's word W (the paper's
+word-size specialization) and the buffer's own itemsize: a buffer of
+elements narrower than W is handed over flat at its own width (a
+same-width bitcast, free), never re-viewed as wider words.  On the TPU a
+flat narrow array keeps each element in its own 32-bit container
+(``uint8[n]`` tiles as ``T(1024)(128)(4,1)``), so any wider or narrower
+view of it, bytes to words or words to bytes, is a relayout copy through
+a lane-padded ``[n, 4]`` shape that moves ~17 GB for a 64 MiB buffer.
+Buffers whose itemsize is not narrower than W are re-viewed as bytes and
+then as W-byte words, a relayout where the tiled layouts differ.  Each
+phase traces under its
 ``repro.obs.scope``: ``tempi.pack``, ``tempi.unpack``, ``tempi.stencil``,
 and ``tempi.bytes`` for every byte/word re-view, so a profiler trace
 splits their device time.
@@ -185,11 +193,24 @@ def byte_view(arr: jax.Array) -> jax.Array:
 
 @scope("bytes")
 def unbyte_view(b: jax.Array, dtype, shape) -> jax.Array:
-    """Inverse of :func:`byte_view`."""
-    if dtype == jnp.uint8:
+    """Inverse of :func:`byte_view`, and of :func:`_element_view`."""
+    if dtype == b.dtype:
         return b.reshape(shape)
-    w = jnp.dtype(dtype).itemsize
-    return jax.lax.bitcast_convert_type(b.reshape(-1, w), dtype).reshape(shape)
+    w = jnp.dtype(dtype).itemsize // b.dtype.itemsize
+    if w > 1:
+        b = b.reshape(-1, w)
+    return jax.lax.bitcast_convert_type(b, dtype).reshape(shape)
+
+
+@scope("bytes")
+def _element_view(arr: jax.Array) -> jax.Array:
+    """Flat ``uint{8k}`` view of an array of ``k``-byte elements: a
+    same-width bitcast, which keeps the TPU layout (no copy)."""
+    if arr.dtype == jnp.bool_:
+        raise TypeError("bool buffers are not byte-addressable; cast first")
+    flat = arr.reshape(-1)
+    u = _UINT[arr.dtype.itemsize]
+    return flat if flat.dtype == u else jax.lax.bitcast_convert_type(flat, u)
 
 
 @scope("bytes")
@@ -215,7 +236,7 @@ def words_to_bytes(x: jax.Array) -> jax.Array:
 class _Plan:
     """Host-side execution plan for one (committed type, incount)."""
 
-    __slots__ = ("sb", "reps", "rep_extent", "geom", "kind")
+    __slots__ = ("sb", "reps", "rep_extent", "geom", "kind", "narrow")
 
     def __init__(self, ct: CommittedType, incount: int):
         sb = ct.block
@@ -247,6 +268,35 @@ class _Plan:
         self.geom = (
             plan_geometry(sb) if sb is not None and sb.ndims in (2, 3) else None
         )
+        self.narrow: Dict[int, Optional[PackGeometry]] = {}
+
+    def narrowed(self, k: int) -> Optional[PackGeometry]:
+        """The leaf's geometry at a ``k``-byte kernel word, cached per
+        ``k`` (see :func:`_narrow_geometry`); None for the word path,
+        and for 3D+ types repeated on the host."""
+        if k not in self.narrow:
+            self.narrow[k] = (
+                _narrow_geometry(self.sb, self.geom, k) if self.reps == 1
+                else None
+            )
+        return self.narrow[k]
+
+
+def _narrow_geometry(
+    sb: StridedBlock, geom: Optional[PackGeometry], k: int
+) -> Optional[PackGeometry]:
+    """``geom`` re-planned at a ``k``-byte kernel word, for a buffer of
+    ``k``-byte elements narrower than its word W.  None where the W-word
+    path stays: ``k`` is not narrower, or the narrowed row group does
+    not sit on the sublane tile of ``k``-byte elements (Mosaic tiles
+    8-bit blocks as (32, 128) and 16-bit ones as (16, 128)), or there is
+    none (whole view planes)."""
+    if geom is None or k >= geom.word_bytes:
+        return None
+    g = plan_geometry(sb, word_bytes=k)
+    if g is None or g.plane_block or g.group % (32 // k):
+        return None
+    return g
 
 
 def _plan(ct: CommittedType, incount: int) -> _Plan:
@@ -267,32 +317,48 @@ def _plan(ct: CommittedType, incount: int) -> _Plan:
 
 @scope("bytes")
 def _prep_words(b: jax.Array, geom: PackGeometry) -> jax.Array:
-    """bytes -> padded (rows_padded, pitch) word view."""
-    w = geom.word_bytes
+    """Flat ``b`` -> padded (rows_padded, pitch) kernel-word view.
+
+    ``b`` holds either bytes, re-viewed here as the geometry's W-byte
+    words, or elements already as wide as the kernel word (a buffer
+    narrower than W, see :func:`_narrow_geometry`), which then only
+    reshapes: one ordinary relayout copy on the TPU, where a wider view
+    of a narrow buffer would expand it through a lane-padded ``[n, W]``
+    shape."""
+    per = geom.word_bytes // b.dtype.itemsize  # elements per kernel word
     n = b.shape[0]
-    need_bytes = geom.rows_padded * geom.pitch * w
-    if n % w or n < need_bytes:
-        pad = max(need_bytes, ((n + w - 1) // w) * w) - n
+    need = geom.rows_padded * geom.pitch * per
+    if n % per or n < need:
+        pad = max(need, ((n + per - 1) // per) * per) - n
         b = jnp.pad(b, (0, pad))
-    words = as_words(b, w)
+    words = as_words(b, per)
     words = words[: geom.rows_padded * geom.pitch]
     return words.reshape(geom.rows_padded, geom.pitch)
 
 
-#: words per step of :func:`packed_bytes`
+#: bytes per step of :func:`packed_bytes` / :func:`packed_words`, in
+#: 4-byte words
 _BYTES_CHUNK_WORDS = 4096
+
+
+def _chunk_rows(lanes: int, itemsize: int) -> int:
+    """Rows of ``lanes`` elements per loop step: the same bytes a step
+    whatever the kernel word."""
+    return max(1, _BYTES_CHUNK_WORDS * 4 // (lanes * itemsize))
 
 
 @scope("bytes")
 def packed_bytes(packed: jax.Array) -> jax.Array:
-    """Flat bytes of a (planes, rows, lanes) packed word array, converted
-    ``_BYTES_CHUNK_WORDS`` words per loop step.  Turning narrow word rows
-    into flat bytes is a relayout on the TPU, and the TPU compiler's time
-    for it grows with the array: about 95 s for one 1 MiB object in one
-    piece (v5e, installed libtpu), about a second in chunks."""
+    """Flat bytes of a (planes, rows, lanes) packed kernel-word array,
+    converted ``4 * _BYTES_CHUNK_WORDS`` bytes per loop step (word-1
+    kernels too: a step of a narrow word would carry fewer bytes).
+    Turning narrow word rows into flat bytes is a relayout on the TPU,
+    and the TPU compiler's time for it grows with the array: about 95 s
+    for one 1 MiB object in one piece (v5e, installed libtpu), about a
+    second in chunks."""
     rows = packed.reshape(-1, packed.shape[-1])
     m, k = rows.shape
-    per = max(1, _BYTES_CHUNK_WORDS // k)
+    per = _chunk_rows(k, rows.dtype.itemsize)
     if m <= per:
         return words_to_bytes(rows.reshape(-1))
     nfull, tail = divmod(m, per)
@@ -325,7 +391,7 @@ def packed_words(packed: jax.Array, w: int, shape) -> jax.Array:
     packed = jax.lax.optimization_barrier(packed)
     k = shape[-1]
     m = packed.shape[0] // (w * k)
-    per = max(1, _BYTES_CHUNK_WORDS // k)
+    per = _chunk_rows(k, w)
     if m <= per:
         return as_words(packed, w).reshape(shape)
     nfull, tail = divmod(m, per)
@@ -358,19 +424,29 @@ def run_unpack_kernel(
 ):
     """Drive a (dst2d, pk3, geom, interpret) -> dst2d unpack kernel:
     word-view prep, kernel, and tail reassembly for bytes the 2D view
-    does not cover."""
+    does not cover.  Returns ``b`` updated, flat and of ``b``'s dtype:
+    elements at the kernel word's width come back by the inverse
+    reshape alone, never as a whole-buffer byte view."""
     n = b.shape[0]
-    covered = geom.rows_padded * geom.pitch * geom.word_bytes
+    per = geom.word_bytes // b.dtype.itemsize  # elements per kernel word
+    covered = geom.rows_padded * geom.pitch * per
     dst2d = _prep_words(b, geom)
     pk3 = packed_words(
         packed, geom.word_bytes, (geom.planes, geom.rows, geom.lanes)
     )
-    out2d = kernel(dst2d, pk3, geom, interpret=interpret)
-    out_b = words_to_bytes(out2d.reshape(-1))
+    out = _unprep_words(kernel(dst2d, pk3, geom, interpret=interpret), b.dtype)
     if covered >= n:
-        return out_b[:n]
+        return out[:n]
     # the 2D word view only covers the strided region; keep the tail
-    return jnp.concatenate([out_b, b[covered:]])
+    return jnp.concatenate([out, b[covered:]])
+
+
+@scope("bytes")
+def _unprep_words(out2d: jax.Array, dtype) -> jax.Array:
+    """Inverse of :func:`_prep_words`: the 2D view flat, as ``dtype``
+    (bytes, or elements of the kernel word's width)."""
+    out = out2d.reshape(-1)
+    return out if out.dtype == dtype else words_to_bytes(out)
 
 
 # ---------------------------------------------------------------------------
@@ -426,6 +502,9 @@ def pack(
         out = strat.pack_planes(view, plan.geom, interpret)
         if out is not None:
             return out
+    geom = plan.narrowed(buf.dtype.itemsize)
+    if strat.leaf_kernels and geom is not None:
+        return strat.pack_leaf(_element_view(buf), plan.sb, geom, interpret)
     b = byte_view(buf)
     if plan.kind is KernelKind.GENERIC or plan.sb is None:
         return refk.pack_ref(b, ct.block, incount, ct.extent)  # pragma: no cover
@@ -452,10 +531,14 @@ def pack_block(
     strat = _resolve(strategy)
     if interpret is None:
         interpret = _interpret_default()
-    b = byte_view(buf)
     if sb.ndims == 1:
+        b = byte_view(buf)
         return jax.lax.dynamic_slice(b, (sb.start,), (sb.counts[0],))
-    return strat.pack_leaf(b, sb, plan_geometry(sb), interpret)
+    geom = plan_geometry(sb)
+    narrow = _narrow_geometry(sb, geom, buf.dtype.itemsize)
+    if strat.leaf_kernels and narrow is not None:
+        return strat.pack_leaf(_element_view(buf), sb, narrow, interpret)
+    return strat.pack_leaf(byte_view(buf), sb, geom, interpret)
 
 
 def _unpack_one(
@@ -502,6 +585,12 @@ def unpack(
         )
         if out is not None:
             return jax.lax.bitcast_convert_type(out, buf.dtype)
+    geom = plan.narrowed(buf.dtype.itemsize)
+    if strat.leaf_kernels and geom is not None:
+        out = strat.unpack_leaf(
+            _element_view(buf), packed, plan.sb, geom, interpret
+        )
+        return unbyte_view(out, buf.dtype, buf.shape)
     b = byte_view(buf)
     if plan.kind is KernelKind.GENERIC or plan.sb is None:  # pragma: no cover
         out = refk.unpack_ref(b, packed, ct.block, incount, ct.extent)

@@ -9,6 +9,8 @@ incounts.  Kernels run in interpret mode on CPU.
 import numpy as np
 import pytest
 
+import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 
 from repro.core import (
@@ -25,7 +27,7 @@ from repro.core import (
 )
 from repro.kernels import pack, plan_geometry, unpack
 from repro.kernels.geometry import VMEM_BUDGET_BYTES
-from repro.kernels.ops import byte_view
+from repro.kernels.ops import byte_view, pack_block
 from repro.kernels.ref import pack_ref, unpack_ref
 
 REG = TypeRegistry()
@@ -171,6 +173,117 @@ def test_user_dtype_buffers():
     np.testing.assert_array_equal(np.asarray(got), want)
     out = unpack(jnp.zeros((64, 64), jnp.float32), got, ct)
     assert out.shape == (64, 64) and out.dtype == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# kernels at the buffer's own element width
+# ---------------------------------------------------------------------------
+
+#: (label, datatype, incount): byte objects whose geometry's word is 4
+#: bytes; the row group (64, 32, 16 or 8 rows) decides whether a uint8
+#: or 16-bit buffer runs the kernels at its own width or keeps the
+#: 32-bit word path
+NARROW_OBJECTS = [
+    ("b4 p256", Vector(64, 4, 256, BYTE), 1),
+    ("b8 p512", Vector(64, 8, 512, BYTE), 1),
+    ("b64 p1536", Vector(32, 64, 1536, BYTE), 1),
+    ("b8 p512 start", Subarray((128, 512), (64, 8), (32, 40), BYTE), 1),
+    ("b8 p512 start incount2",
+     Subarray((96, 512), (64, 8), (32, 40), BYTE), 2),
+    ("b8 p512 group16", Vector(48, 8, 512, BYTE), 1),
+    ("b8 p512 group8", Vector(40, 8, 512, BYTE), 1),
+]
+
+
+def _buffer_of(raw: np.ndarray, dtype) -> jnp.ndarray:
+    """The bytes ``raw`` as a flat buffer of ``dtype``."""
+    uint = {1: np.uint8, 2: np.uint16, 4: np.uint32}[jnp.dtype(dtype).itemsize]
+    return jax.lax.bitcast_convert_type(jnp.asarray(raw.view(uint)), dtype)
+
+
+@pytest.mark.parametrize("strat", KERNEL_STRATEGIES)
+@pytest.mark.parametrize(
+    "dtype", [jnp.uint8, jnp.uint16, jnp.bfloat16, jnp.float32],
+    ids=["u8", "u16", "bf16", "f32"],
+)
+@pytest.mark.parametrize(
+    "label, dt, incount", NARROW_OBJECTS, ids=[o[0] for o in NARROW_OBJECTS]
+)
+def test_flat_buffer_roundtrip_at_element_width(label, dt, incount, dtype,
+                                                strat):
+    """Pack and unpack of flat buffers of 1-, 2- and 4-byte elements
+    equal the byte oracle exactly: the packed bytes, and the destination
+    inside the blocks and between them (and in its uncovered tail)."""
+    ct = REG.commit(dt)
+    width = jnp.dtype(dtype).itemsize
+    nbytes = -(-(ct.extent * incount + 37) // width) * width
+    src = _buffer_of(RNG.integers(0, 256, nbytes, dtype=np.uint8), dtype)
+    dst0 = _buffer_of(RNG.integers(0, 256, nbytes, dtype=np.uint8), dtype)
+    want = pack_ref(byte_view(src), ct.block, incount, ct.extent)
+    want_dst = unpack_ref(byte_view(dst0), want, ct.block, incount, ct.extent)
+    got = pack(src, ct, incount=incount, strategy=strat)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if incount == 1:
+        np.testing.assert_array_equal(
+            np.asarray(pack_block(src, ct.block, strategy=strat)),
+            np.asarray(want),
+        )
+    out = unpack(dst0, got, ct, incount=incount, strategy=strat)
+    assert out.shape == dst0.shape and out.dtype == dst0.dtype
+    np.testing.assert_array_equal(
+        np.asarray(byte_view(out)), np.asarray(want_dst)
+    )
+
+
+def test_narrowed_geometry_follows_the_sublane_tile():
+    """The kernel word narrows to the buffer's width only where the
+    narrowed row group sits on that width's sublane tile (32 rows of
+    bytes, 16 of 16-bit elements)."""
+    from repro.kernels.ops import _narrow_geometry
+
+    def narrowed(dt, k):
+        sb = REG.commit(dt).block
+        return _narrow_geometry(sb, plan_geometry(sb), k)
+
+    g = narrowed(Vector(64, 8, 512, BYTE), 1)
+    assert (g.word_bytes, g.lanes, g.pitch, g.group) == (1, 8, 512, 64)
+    assert narrowed(Vector(64, 8, 512, BYTE), 4) is None  # not narrower
+    assert narrowed(Vector(48, 8, 512, BYTE), 1) is None  # 16-row group
+    assert narrowed(Vector(48, 8, 512, BYTE), 2).group == 16
+    assert narrowed(Vector(40, 8, 512, BYTE), 2) is None  # 8-row group
+    assert narrowed(Vector(13, 8, 512, BYTE), 1) is None  # plane blocks
+
+
+@pytest.mark.parametrize("strat", KERNEL_STRATEGIES)
+def test_byte_buffer_is_never_viewed_as_words(strat):
+    """The pack cell's traced pack and unpack of a flat uint8 buffer hold
+    no bitcast-convert of the whole buffer (the TPU would relayout all
+    of it through a lane-padded [n, 4] shape)."""
+    ct = REG.commit(Vector(131072, 8, 512, BYTE))
+    n = 131072 * 512
+    buf = jax.ShapeDtypeStruct((n,), jnp.uint8)
+    packed = jax.ShapeDtypeStruct((ct.size,), jnp.uint8)
+
+    def whole_buffer_bitcasts(jaxpr):
+        found = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "bitcast_convert_type":
+                aval = eqn.invars[0].aval
+                found += aval.size * aval.dtype.itemsize >= n
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (list, tuple)) else [param]:
+                    if isinstance(sub, jax_core.ClosedJaxpr):
+                        sub = sub.jaxpr
+                    if isinstance(sub, jax_core.Jaxpr):
+                        found += whole_buffer_bitcasts(sub)
+        return found
+
+    for fn, args in (
+        (lambda b: pack(b, ct, strategy=strat, interpret=False), (buf,)),
+        (lambda b, p: unpack(b, p, ct, strategy=strat, interpret=False),
+         (buf, packed)),
+    ):
+        assert whole_buffer_bitcasts(jax.make_jaxpr(fn)(*args).jaxpr) == 0
 
 
 def test_geometry_planner_properties():

@@ -122,6 +122,37 @@ def test_compiled_kernels_carry_their_names_and_scopes(one_chip, label,
         assert "tempi.bytes" in compiled.as_text()
 
 
+@pytest.mark.parametrize("strategy", ["production", "dma"])
+def test_pack_cell_programs_never_view_the_buffer_as_words(one_chip, tmp_path,
+                                                           strategy):
+    # the pack cell's two programs: Vector(131072, 8, 512, BYTE) out of
+    # and into a flat 64 MiB uint8 buffer.  Viewing that buffer as 32-bit
+    # words relayouts all of it through a [16777216, 4] shape, ~18 GB of
+    # traffic a program; the byte-wide kernels read the pitch rows once
+    from repro.comm.perfmodel import TPU_V5E
+    from repro.core import BYTE, Vector
+    from repro.measure.production import production_communicator
+
+    comm, _ = production_communicator(tmp_path, calibrate=False,
+                                      params=TPU_V5E)
+    ct = comm.commit(Vector(131072, 8, 512, BYTE))
+    strat = (comm.select(ct, 1, wire=False) if strategy == "production"
+             else comm.strategies.get(strategy))
+    n = 131072 * 512
+    buf = jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip)
+    packed = jax.ShapeDtypeStruct((ct.size,), jnp.uint8, sharding=one_chip)
+    pack = jax.jit(lambda b: strat.pack(b, ct, interpret=False))
+    unpack = jax.jit(lambda b, p: strat.unpack(b, p, ct, interpret=False),
+                     donate_argnums=0)
+    for compiled in (
+        pack.lower(buf).compile(), unpack.lower(buf, packed).compile()
+    ):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert f"[{n // 4},4]" not in text
+        assert compiled.cost_analysis()["bytes accessed"] < 1e9
+
+
 def test_native_ragged_exchange_compiles_on_2x2(topo):
     assert has_ragged_all_to_all(topo.devices)
     spec = HaloSpec(grid=(4, 1, 1), interior=(16, 16, 16), radius=1)
